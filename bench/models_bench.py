@@ -23,8 +23,8 @@ Two leg families, JSON rows to stdout like the other bench emitters:
   BITWISE identical per leg — the bench doubles as the parity check.
 
 Every leg follows the null-on-failure discipline: a failed leg emits
-``null`` metrics and the bench exits 0 (never a fabricated number);
-the device probe is the hang-proof ``resilience.safe_devices`` one.
+``null`` metrics and the bench exits 0 (never a fabricated number).
+Devices come from ``jax.devices()`` in process.
 
 Run:  timeout -k 10 900 python bench/models_bench.py [--n 16]
       [--steps 40] [--no-split]
@@ -63,9 +63,9 @@ def emit(row):
 
 
 def probe():
-    from dccrg_tpu.resilience import safe_devices
+    import jax
 
-    return safe_devices(timeout=120)
+    return jax.devices()
 
 
 def _bench_loop(run_fn, steps, reps=3):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def all_gather(x, axis_name: str):

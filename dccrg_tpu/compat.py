@@ -1,91 +1,30 @@
-"""Version-compatibility shims for jax API drift.
-
-``shard_map`` has moved twice (experimental -> top level) and renamed
-its replication-check flag (``check_rep`` -> ``check_vma``). The
-callers in this package write the newest spelling; this shim adapts it
-to whatever the installed jax accepts, so a container pinned to an
-older jax runs the same code instead of failing every sharded program
-at trace time.
-"""
+"""JAX runtime set-up shared by the entry points: the persistent
+compile cache."""
 
 from __future__ import annotations
 
-import inspect
+import os
+from pathlib import Path
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    from jax import shard_map as _raw_shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-_PARAMS = inspect.signature(_raw_shard_map).parameters
-
-if "check_vma" in _PARAMS:
-    shard_map = _raw_shard_map
-else:
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None and "check_rep" in _PARAMS:
-            kw["check_rep"] = check_vma
-        return _raw_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed, so that one checkout's runs find each other's compiles: the
+# cache path is part of the key, and a temp or per-run path never hits
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parent.parent / ".jax_cache"
 
 
-def pallas_compiler_params(**kw):
-    """TPU Pallas compiler params under either spelling:
-    ``pltpu.CompilerParams`` (newer jax) or ``pltpu.TPUCompilerParams``
-    (older releases)."""
-    from jax.experimental.pallas import tpu as pltpu
+def use_compile_cache(directory: str | os.PathLike | None = None) -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
 
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
-def enable_persistent_cache(directory: str) -> bool:
-    """Point jax's persistent compilation cache at ``directory``,
-    across the API drift between releases: the config keys
-    (``jax_compilation_cache_dir`` plus the min-compile-time /
-    min-entry-size gates that default CPU programs OUT of the cache)
-    on newer jax, ``compilation_cache.set_cache_dir`` on older ones.
-    Idempotent; returns False when no spelling is accepted (the
-    caller degrades to cold compiles — never an error)."""
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, the cache stays there
+    and ``directory`` is ignored. Otherwise the
+    cache goes to ``directory``, by default ``<checkout>/.jax_cache``.
+    Both size gates are lowered, since they would keep the CPU's small,
+    fast compiles out of the cache."""
     import jax
 
-    ok = False
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(directory))
-        ok = True
-    except Exception:  # noqa: BLE001 - drift probe, fallback below
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache)
-
-            compilation_cache.set_cache_dir(str(directory))
-            ok = True
-        except Exception:  # noqa: BLE001
-            return False
-    # CPU programs compile in milliseconds and serialize small: both
-    # default gates would silently keep them out of the cache
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs",
-                       0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes",
-                       -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # noqa: BLE001 - older jax: gate absent
-            pass
-    return ok
-
-
-def pallas_interpret_mode(interpret: bool):
-    """The value ``pl.pallas_call(..., interpret=...)`` wants for TPU
-    interpret mode: newer jax models it as ``pltpu.InterpretParams()``;
-    older releases take the plain boolean. False either way when not
-    interpreting."""
-    if not interpret:
-        return False
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.InterpretParams()
-    except AttributeError:
-        return True
+    path = os.environ.get(CACHE_ENV) or str(
+        directory if directory is not None else DEFAULT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from . import background
 from . import faults
@@ -310,16 +310,12 @@ def _run_slotwise(kernel, cell_fields, fields, gather, offs_col, mask_col,
     thread through ``optimization_barrier``: the per-slot gathers have
     no data dependency on each other, so without the barrier XLA's
     scheduler hoists ALL slots' rolls to the front and every column is
-    live at once. NOTE the barrier is necessary but — per the measured
-    chip artifact (bench/chip_results/bench_main_slotwise.out) — not
-    sufficient at the largest sizes: the 512^3 roll-mode run still
-    kept ~9 co-resident 512 MB roll temps and OOM'd (~0.3 GB over a
-    16 GB budget at 50% fragmentation). Peak HBM is REDUCED versus the
-    dense [L, S] contract, not hard-bounded at O(cells); forcing full
-    sequencing (lax.scan over slots / donated carry) is the open
-    follow-up if 512^3-on-one-chip matters. On an OOM at dispatch the
-    resilience layer (resilience.guarded_step) degrades to the next
-    gather mode instead of crashing the run."""
+    live at once. Peak HBM is REDUCED versus the dense [L, S] contract,
+    not hard-bounded at O(cells): the 512^3 advection step program
+    compiles for a v5e with 6.6 GB of temporaries, and 20 steps of it
+    peaked at 5.9 GB in use on the chip (chip_smoke.py, PR 21). On an
+    OOM at dispatch the resilience layer (resilience.guarded_step)
+    degrades to the next gather mode instead of crashing the run."""
     carry = kernel.init(cell_fields, *extra)
     names = list(fields)
     vals = [fields[n] for n in names]
@@ -338,12 +334,10 @@ def _run_slotwise(kernel, cell_fields, fields, gather, offs_col, mask_col,
 class SlotwiseKernel:
     """Memory-lean stencil kernel: the bulk pass feeds it one neighbor
     slot (stencil leg) at a time, avoiding the dense contract's
-    O(cells * slots) neighbor stack. Measured effect on chip
-    (bench/chip_results/bench_main_slotwise.out): peak HBM drops
-    substantially, but XLA's scheduler still co-locates several slot
-    temporaries, so 512^3 remained slightly over a single chip's HBM
-    budget in roll mode — treat this as *reduced*, not O(cells), peak
-    HBM until a passing 512^3 run exists. Three callables:
+    O(cells * slots) neighbor stack. XLA's scheduler still co-locates
+    several slot temporaries, so treat this as *reduced*, not O(cells),
+    peak HBM; the 512^3 advection grid fits one v5e's 16 GB in roll
+    mode (see _run_slotwise). Three callables:
 
     - ``init(cell_fields, *extra) -> carry``
     - ``slot(carry, cell_fields, nbr_j, offs_j, mask_j, *extra) ->

@@ -353,7 +353,7 @@ def conservation_sums(grid, fields) -> np.ndarray:
     from jax.sharding import PartitionSpec as P
 
     from . import comm
-    from .compat import shard_map
+    from jax import shard_map
 
     names = tuple(fields)
     if not names:

@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import pallas_compiler_params, pallas_interpret_mode
-
 
 def make_rotation_step(
     shape, dtype=jnp.float32, tile=(8, 128), cell_length=None, steps_per_pass=1,
@@ -118,7 +116,7 @@ def make_rotation_step(
             ),
         ]
 
-    def upwind(s, cx, cy_col):
+    def upwind(s, cx, cx_pos, cy_col, cy_pos):
         """One upwind update: input s of R rows -> output of R - 2 rows
         (the interior), with cy_col/cy_sign (R - 2 rows) aligned to the
         output.
@@ -141,13 +139,13 @@ def make_rotation_step(
         rc = s[1 : R - 1]
         # one-sided differences along x: both sides slice one array
         d_x = s[0 : R - 1] - s[1:R]  # d_x[i] = s[i] - s[i+1]
-        dxt = cx * jnp.where(cx >= 0, d_x[0 : R - 2], d_x[1 : R - 1])
+        dxt = cx * jnp.where(cx_pos, d_x[0 : R - 2], d_x[1 : R - 1])
         # y: d_y[j] = rc[j] - rc[(j+1) % Y]; the lo-side difference is
         # its +1 roll (periodic wrap falls out of the concat order)
         r_yp = jnp.concatenate([rc[:, 1:, :], rc[:, :1, :]], axis=1)
         d_y = rc - r_yp
         d_ym = jnp.concatenate([d_y[:, Y - 1 :, :], d_y[:, : Y - 1, :]], axis=1)
-        dyt = cy_col * jnp.where(cy_col >= 0, d_ym, d_y)
+        dyt = cy_col * jnp.where(cy_pos, d_ym, d_y)
         return rc + dxt + dyt
 
     def kernel(dt_ref, rho_hbm, vxf_ref, vyf_ref, out_ref, body, sems):
@@ -174,21 +172,29 @@ def make_rotation_step(
         dt = dt_ref[0]
         # fold dt/dlen into the 1-D velocity vectors once per pass;
         # the minor-dim-inserting reshapes run in float32 (Mosaic only
-        # supports them for 32-bit types) and cast straight back, so
+        # supports them for 32-bit types) and are cast back below, so
         # everything downstream stays in the storage dtype
         f32 = jnp.float32
         cx = (vxf_ref[0, :].astype(f32).reshape(1, Y, 1)
-              * (dt.astype(f32) * rdx)).astype(dtype)
+              * (dt.astype(f32) * rdx))
         # extended vy: index i of vyf_ref holds vy[(i - 8) % X], so the
         # slice at x0 (sublane-aligned) covers global rows x0-8..x0+tx+7
         cy_wide = (vyf_ref[pl.ds(x0, tx + 16), 0].astype(f32)
                    .reshape(tx + 16, 1, 1)
-                   * (dt.astype(f32) * rdy)).astype(dtype)
+                   * (dt.astype(f32) * rdy))
+        # broadcast along the lanes and take the upwind signs while
+        # still float32: Mosaic cannot broadcast a 16-bit vector in
+        # sublanes and lanes at once, and v5e has no bfloat16 compare
+        cx = jnp.broadcast_to(cx, (1, Y, tz))
+        cy_wide = jnp.broadcast_to(cy_wide, (tx + 16, 1, tz))
+        cx_pos, cy_pos = cx >= 0, cy_wide >= 0
+        cx, cy_wide = cx.astype(dtype), cy_wide.astype(dtype)
 
         s = body[slot]  # rows cover global [x0 - H, x0 + tx + H)
         for k in range(sp):
             g = H - k - 1  # halo width remaining after this sub-step
-            s = upwind(s, cx, cy_wide[8 - g : 8 - g + tx + 2 * g])
+            rows = slice(8 - g, 8 - g + tx + 2 * g)
+            s = upwind(s, cx, cx_pos, cy_wide[rows], cy_pos[rows])
         out_ref[:] = s
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -215,9 +221,9 @@ def make_rotation_step(
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        interpret=pallas_interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
         out_shape=jax.ShapeDtypeStruct((X, Y, Z), dtype),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             # deep temporal blocking holds several flux temporaries live;
             # let Mosaic use more than the 16 MiB default scoped VMEM
             vmem_limit_bytes=96 * 1024 * 1024,

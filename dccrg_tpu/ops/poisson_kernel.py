@@ -37,8 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import pallas_compiler_params, pallas_interpret_mode
-
 
 def make_laplacian_matvec(shape, cell_length=None, periodic=(True, True, True),
                           dtype=jnp.float32, tx=8, interpret=False):
@@ -162,9 +160,9 @@ def make_laplacian_matvec(shape, cell_length=None, periodic=(True, True, True),
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        interpret=pallas_interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
         out_shape=jax.ShapeDtypeStruct((X, Y, Z), jnp.dtype(dtype)),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=96 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(

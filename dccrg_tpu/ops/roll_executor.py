@@ -43,8 +43,9 @@ single-device closed-form plan, scalar cell fields, a SlotwiseKernel,
 ``L % 1024 == 0``, and halos that fit the tiling. On CPU backends the
 kernel runs under Pallas TPU interpret mode (CI's parity suite,
 tests/test_bulk_executor.py); lane rotates (minor-dim concats) and
-in-kernel integer div/mod are Mosaic-supported but unmeasured on chip
-until bench/chip_session.sh's executor A/B runs.
+in-kernel integer div/mod are Mosaic-supported: the executor compiles
+for a v5e at 512^3 (tests/test_chip_compile.py at 128^3) but has not
+been timed on the chip.
 """
 
 from __future__ import annotations
@@ -57,8 +58,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ..compat import pallas_compiler_params, pallas_interpret_mode
 
 _LANES = 128
 _SUBLANES = 8
@@ -347,9 +346,9 @@ def make_bulk_pass(spec, kernel, fields_in, fields_out, dtypes,
     call = pl.pallas_call(
         body,
         grid_spec=grid_spec,
-        interpret=pallas_interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
         out_shape=out_shapes,
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=96 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(

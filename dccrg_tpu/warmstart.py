@@ -8,10 +8,10 @@ churn puts that storm exactly where the fleet is weakest. This module
 closes the cold-start half of the streaming front door:
 
 **Persistent compile cache** — ``DCCRG_COMPILE_CACHE=<dir>`` points
-jax's persistent compilation cache at ``<dir>/xla`` (via the
-:func:`~dccrg_tpu.compat.enable_persistent_cache` drift shim) and
-keeps our own **program-key manifest** next to it: one CRC-framed
-record per (shape, periodicity, schema, kernel, dtype, capacity,
+jax's persistent compilation cache at ``<dir>/xla`` (via
+:func:`~dccrg_tpu.compat.use_compile_cache`, so a set
+``JAX_COMPILATION_CACHE_DIR`` wins) and keeps our own **program-key
+manifest** next to it: one CRC-framed record per (shape, periodicity, schema, kernel, dtype, capacity,
 integrity-flag) bucket key ever compiled, written with the intake
 spool's durability discipline (temp sibling + fsync + atomic rename —
 :func:`dccrg_tpu.coord.write_sealed_file`), so two ranks on one host
@@ -238,11 +238,12 @@ def job_for_bucket(bucket_key):
 
 def ensure_cache(directory: str) -> str:
     """Create the cache directory tree (idempotent) and point jax's
-    persistent compilation cache at its ``xla/`` half."""
+    persistent compilation cache at its ``xla/`` half, unless
+    ``JAX_COMPILATION_CACHE_DIR`` already places it."""
     directory = str(directory)
     for d in ("", MANIFEST_DIR, QUARANTINE_DIR, XLA_DIR):
         os.makedirs(os.path.join(directory, d), exist_ok=True)
-    compat.enable_persistent_cache(os.path.join(directory, XLA_DIR))
+    compat.use_compile_cache(os.path.join(directory, XLA_DIR))
     return directory
 
 

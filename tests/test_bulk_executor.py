@@ -280,10 +280,10 @@ def test_fleet_bulk_bucket_matches_table_path(monkeypatch):
 
 def test_overlap_cpu_default_off(monkeypatch):
     """The satellite pin: overlapped fused steps default OFF on the
-    CPU backend (measured 0.89x there, PERF.md); DCCRG_OVERLAP=1
-    still forces it."""
+    CPU backend (measured 0.89x there, PERF.md) and on on accelerators;
+    DCCRG_OVERLAP=1 still forces it."""
     monkeypatch.delenv("DCCRG_OVERLAP", raising=False)
     g = make_diffuse_grid((True, True, True))
-    assert g._use_overlap() is False
+    assert g._use_overlap() is g._on_accelerator()
     monkeypatch.setenv("DCCRG_OVERLAP", "1")
     assert g._use_overlap() is True
