@@ -8,8 +8,8 @@ finally runs two more alternating unrefine/refine commits so the
 steady-state adapt loop (warm arena, stable sticky-cap shapes) is on
 record too.  ``--no-reuse`` clears the stream cache before every
 re-commit, isolating the reuse machinery's contribution.  Per-phase
-timings come from hybrid.py's phase marks via ``_PHASE_SINK`` (no
-stdout parsing).
+timings (classify, tables) come from the plan builder's
+``dccrg_plan_phase_seconds{phase}`` gauge (no stdout parsing).
 
 ``--overlap`` runs the zero-stall leg instead: the same adapt epochs
 with a serving loop (small run_steps quanta) around them, measuring
@@ -49,26 +49,9 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 import dccrg_tpu as dt  # noqa: E402
-from dccrg_tpu import hybrid  # noqa: E402
+from dccrg_tpu import telemetry  # noqa: E402
 
-
-def _phase_groups(records):
-    """Collapse the raw (label, seconds) marks into the four coarse
-    recommit phases."""
-    groups = {"classify": 0.0, "hard_streams": 0.0, "easy_far_tables": 0.0,
-              "hard_tables": 0.0, "layout_other": 0.0}
-    for label, secs in records:
-        if label.startswith("classify"):
-            groups["classify"] += secs
-        elif label.startswith("hard streams"):
-            groups["hard_streams"] += secs
-        elif "far" in label or "easy" in label:
-            groups["easy_far_tables"] += secs
-        elif "hard" in label:
-            groups["hard_tables"] += secs
-        else:
-            groups["layout_other"] += secs
-    return {k: round(v, 3) for k, v in groups.items()}
+PLAN_PHASES = ("classify", "tables")  # what a hybrid re-commit marks
 
 
 def _commit(g, reuse):
@@ -76,15 +59,13 @@ def _commit(g, reuse):
         # fingerprint mismatch -> full rebuild (streams recomputed);
         # the arena still serves warm buffers, isolating stream reuse
         g._hybrid_reuse = {}
-    sink = []
-    hybrid._PHASE_SINK = sink
-    try:
-        t0 = time.perf_counter()
-        g.stop_refining()
-        total = time.perf_counter() - t0
-    finally:
-        hybrid._PHASE_SINK = None
-    return total, _phase_groups(sink)
+    t0 = time.perf_counter()
+    g.stop_refining()
+    total = time.perf_counter() - t0
+    reg = telemetry.registry()
+    return total, {p: round(reg.gauge_value(telemetry.PLAN_PHASE_GAUGE,
+                                            phase=p), 3)
+                   for p in PLAN_PHASES}
 
 
 def run_size(n, reuse=True):

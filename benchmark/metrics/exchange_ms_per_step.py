@@ -1,0 +1,11 @@
+"""Device ms per step in the step program's ``dccrg.exchange`` scope
+(the halo send and scatter), on the device with the most
+non-collective time (phases.py). 0.0 where the exchange has no ops."""
+
+from pathlib import Path
+from runpy import run_path
+
+
+def read(rec):
+    phases = run_path(str(Path(__file__).resolve().parents[1] / "phases.py"))
+    return phases["ms_per_step"](rec, "dccrg.exchange")

@@ -821,14 +821,18 @@ class Grid:
 
         # level-0 cells, partitioned (create_level_0_cells, dccrg.hpp:8089)
         n0 = self.mapping.length.total_level0_cells
+        mark = telemetry.phase_timer()
         cells = np.arange(1, n0 + 1, dtype=np.uint64)
         owner = partition_cells(
             self.mapping, cells, self.n_dev, partition or self._lb_method,
             pins=self._pins or None,
         )
+        mark("partition")
         self.initialized = True
-        self._build_plan(cells, owner)
+        self._build_plan(cells, owner)  # marks classify and tables
+        mark = telemetry.phase_timer()
         self._allocate_fields()
+        mark("fields")
         if self._debug:
             from . import verify as _verify
 
@@ -3195,6 +3199,9 @@ class Grid:
             extra = args[n_static + n_out:]
 
             def step(_, state):
+                # each phase in a named scope: the compiled ops carry it
+                # in their op_name, which telemetry.publish_scopes maps
+                # to the op names a profiler trace shows
                 state = list(state)
                 if overlap:
                     # sends read only local rows: every round's
@@ -3204,84 +3211,96 @@ class Grid:
                     # (async collective-permute) — the reference's
                     # solve-inner-while-messages-fly overlap
                     # (dccrg.hpp:5046-5413, 2d.cpp:327-343)
-                    payloads = [
-                        _halo_send(state[j], send_rs[xi * n_t + t],
-                                   None if deltas is None else deltas[t],
-                                   axis, n_dev)
-                        for xi, j in enumerate(exch_idx)
-                        for t in range(n_t)
-                    ]
+                    with jax.named_scope("dccrg.exchange"):
+                        payloads = [
+                            _halo_send(state[j], send_rs[xi * n_t + t],
+                                       None if deltas is None else deltas[t],
+                                       axis, n_dev)
+                            for xi, j in enumerate(exch_idx)
+                            for t in range(n_t)
+                        ]
                     # bulk pass on pre-exchange state: rows
                     # [0, n_inner) read no ghosts, so their results
                     # are final; outer rows are redone below
-                    full = dict(statics)
-                    full.update(zip(fields_out, state))
-                    cell_fields = {n: full[n][:L] for n in fields_in}
-                    if slotwise:
-                        result = run_bulk(full, cell_fields, extra)
-                    else:
-                        nbr_fields = {n: gather_nbr(full[n])
-                                      for n in fields_in}
-                        result = kernel(cell_fields, nbr_fields, noffs,
-                                        nmask, *extra)
+                    with jax.named_scope("dccrg.bulk"):
+                        full = dict(statics)
+                        full.update(zip(fields_out, state))
+                        cell_fields = {n: full[n][:L] for n in fields_in}
+                        if slotwise:
+                            result = run_bulk(full, cell_fields, extra)
+                        else:
+                            nbr_fields = {n: gather_nbr(full[n])
+                                          for n in fields_in}
+                            result = kernel(cell_fields, nbr_fields, noffs,
+                                            nmask, *extra)
                     # land the halos, then redo just the outer rows
                     # (with ghost-split, only the rows feeding the
                     # exchanged fields, scattering only the outputs
                     # whose declared ghost reads those fields)
-                    for xi, j in enumerate(exch_idx):
-                        fl = state[j]
-                        for t in range(n_t):
-                            fl = _halo_scatter(fl, recv_rs[xi * n_t + t],
-                                               payloads[xi * n_t + t], R)
-                        state[j] = fl.at[R - 1].set(0)
+                    with jax.named_scope("dccrg.exchange"):
+                        for xi, j in enumerate(exch_idx):
+                            fl = state[j]
+                            for t in range(n_t):
+                                fl = _halo_scatter(
+                                    fl, recv_rs[xi * n_t + t],
+                                    payloads[xi * n_t + t], R)
+                            state[j] = fl.at[R - 1].set(0)
                     if o_tabs:
+                        with jax.named_scope("dccrg.repass"):
+                            full = dict(statics)
+                            full.update(zip(fields_out, state))
+                            cell_fields = {n: full[n][:L]
+                                           for n in fields_in}
+                            om = mask_rows(orc) if slotwise else nmask[orc]
+                            o_cell = {n: cell_fields[n][orc]
+                                      for n in fields_in}
+                            o_nbr = {}
+                            for n in fields_in:
+                                g = full[n][onr]
+                                if use_roll:
+                                    # mirror _make_nbr_gather's
+                                    # mask-zeroing
+                                    mexp = om.reshape(
+                                        om.shape + (1,) * (g.ndim - 2))
+                                    g = jnp.where(mexp, g,
+                                                  jnp.zeros((), g.dtype))
+                                o_nbr[n] = g
+                            o_offs = (offs_rows(orc, om) if slotwise
+                                      else noffs[orc])
+                            o_res = kernel(o_cell, o_nbr, o_offs, om, *extra)
+                            for n in repass:
+                                result[n] = result[n].at[orow].set(
+                                    o_res[n].astype(result[n].dtype),
+                                    mode="drop")
+                else:
+                    if n_dev > 1:
+                        with jax.named_scope("dccrg.exchange"):
+                            for xi, j in enumerate(exch_idx):
+                                state[j] = exchange_one(state[j], xi)
+                    with jax.named_scope("dccrg.bulk"):
                         full = dict(statics)
                         full.update(zip(fields_out, state))
                         cell_fields = {n: full[n][:L] for n in fields_in}
-                        om = mask_rows(orc) if slotwise else nmask[orc]
-                        o_cell = {n: cell_fields[n][orc]
-                                  for n in fields_in}
-                        o_nbr = {}
-                        for n in fields_in:
-                            g = full[n][onr]
-                            if use_roll:
-                                # mirror _make_nbr_gather's mask-zeroing
-                                mexp = om.reshape(om.shape
-                                                  + (1,) * (g.ndim - 2))
-                                g = jnp.where(mexp, g,
-                                              jnp.zeros((), g.dtype))
-                            o_nbr[n] = g
-                        o_offs = (offs_rows(orc, om) if slotwise
-                                  else noffs[orc])
-                        o_res = kernel(o_cell, o_nbr, o_offs, om, *extra)
-                        for n in repass:
-                            result[n] = result[n].at[orow].set(
-                                o_res[n].astype(result[n].dtype),
-                                mode="drop")
-                else:
-                    if n_dev > 1:
-                        for xi, j in enumerate(exch_idx):
-                            state[j] = exchange_one(state[j], xi)
-                    full = dict(statics)
-                    full.update(zip(fields_out, state))
-                    cell_fields = {n: full[n][:L] for n in fields_in}
-                    if slotwise:
-                        result = run_bulk(full, cell_fields, extra)
-                    else:
-                        nbr_fields = {n: gather_nbr(full[n])
-                                      for n in fields_in}
-                        result = kernel(cell_fields, nbr_fields, noffs,
-                                        nmask, *extra)
+                        if slotwise:
+                            result = run_bulk(full, cell_fields, extra)
+                        else:
+                            nbr_fields = {n: gather_nbr(full[n])
+                                          for n in fields_in}
+                            result = kernel(cell_fields, nbr_fields, noffs,
+                                            nmask, *extra)
                 if split:
-                    h_cell = {n: cell_fields[n][hrc] for n in fields_in}
-                    h_nbr = {n: full[n][hnr] for n in fields_in}
-                    h_result = kernel(h_cell, h_nbr, hof, hm, *extra)
-                    for n in fields_out:
-                        result[n] = result[n].at[hr].set(
-                            h_result[n].astype(result[n].dtype), mode="drop"
-                        )
-                for j, n in enumerate(fields_out):
-                    state[j] = state[j].at[:L].set(result[n].astype(state[j].dtype))
+                    with jax.named_scope("dccrg.bulk"):
+                        h_cell = {n: cell_fields[n][hrc] for n in fields_in}
+                        h_nbr = {n: full[n][hnr] for n in fields_in}
+                        h_result = kernel(h_cell, h_nbr, hof, hm, *extra)
+                        for n in fields_out:
+                            result[n] = result[n].at[hr].set(
+                                h_result[n].astype(result[n].dtype),
+                                mode="drop")
+                with jax.named_scope("dccrg.apply"):
+                    for j, n in enumerate(fields_out):
+                        state[j] = state[j].at[:L].set(
+                            result[n].astype(state[j].dtype))
                 return tuple(state)
 
             out = jax.lax.fori_loop(0, n_steps, step, state0)
@@ -3302,7 +3321,11 @@ class Grid:
             check_vma=False,
         )
 
-        fn = jax.jit(lambda *a: mapped(*a))
+        def dccrg_step_loop(*a):
+            # a fixed name: the compiled module is jit_dccrg_step_loop
+            return mapped(*a)
+
+        fn = jax.jit(dccrg_step_loop)
         self._program_cache[key] = fn
         return fn, tables, static_in
 
@@ -3326,29 +3349,26 @@ class Grid:
             self.bg_install()
         fields_in = tuple(fields_in)
         fields_out = tuple(fields_out)
-        with telemetry.span("grid.step"):
+        step_span = telemetry.span("grid.step")
+        with step_span:
             fn, tables, static_in = self.compile_step_loop(
                 kernel, fields_in, fields_out, exchange_fields,
                 neighborhood_id, n_extra=len(extra_args),
             )
-            ov = getattr(self, "last_overlap", None)
-            if ov is not None and ov["mode"] != "off":
-                # the ghost-split measuring stick: outer-re-pass row
-                # slots actually recomputed vs the full re-pass's
-                telemetry.inc("dccrg_outer_repass_rows_total",
-                              ov["rows_split"] * int(n_steps),
-                              mode=ov["mode"])
-                telemetry.inc("dccrg_outer_repass_rows_full_total",
-                              ov["rows_full"] * int(n_steps))
-            out = fn(
+            args = (
                 jnp.int32(n_steps),
                 *tables,
                 *(self.data[n] for n in static_in),
                 *(self.data[n] for n in fields_out),
                 *extra_args,
             )
+            out = fn(*args)
             for n, arr in zip(fields_out, out):
                 self.data[n] = arr
+        if step_span is not telemetry.NULL_SPAN and telemetry.profiling():
+            # the device has this call queued; the op -> phase table of
+            # its program is read from the compile cache, once per program
+            telemetry.publish_scopes(fn, args)
         self._mark_ckpt_dirty(fields_out)
         # DCCRG_WATCHDOG=N: self-check the stepped fields for NaN/Inf
         # every ~N steps (one device-side scalar; see resilience.py) —

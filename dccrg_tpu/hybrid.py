@@ -39,48 +39,12 @@ tables are built lazily on first use, as on the uniform fast path.
 
 from __future__ import annotations
 
-import os
 import threading
-import time
 import weakref
 
 import numpy as np
 
 from . import faults, telemetry
-
-#: Optional phase-record sink: a list that every build appends
-#: ``(label, seconds)`` tuples to (bench/recommit_bench.py installs
-#: one to capture per-phase timings without parsing stdout).
-_PHASE_SINK = None
-
-
-def _phase_timer():
-    """Phase-boundary logger: prints with DCCRG_TIMING=1, records into
-    :data:`_PHASE_SINK` when one is installed, and emits the phases as
-    ``hybrid.<label>`` telemetry spans when tracing is on (so an
-    adapt/recommit epoch's internal cost split — classification, row
-    layout, send/recv lists — lands in the same timeline as the
-    ``grid.recommit`` span wrapping it)."""
-    sink = _PHASE_SINK
-    echo = os.environ.get("DCCRG_TIMING") == "1"
-    trace = telemetry.trace_enabled()
-    if sink is None and not echo and not trace:
-        return lambda label: None
-    state = {"t": time.perf_counter()}
-
-    def mark(label):
-        now = time.perf_counter()
-        dt = now - state["t"]
-        if echo:
-            print(f"[hybrid] {label}: {dt:.3f}s", flush=True)
-        if sink is not None:
-            sink.append((label, dt))
-        if trace:
-            telemetry.record_span("hybrid." + label.replace(" ", "_"), dt)
-        state["t"] = now
-
-    return mark
-
 
 def _fill_chunked(view, value, chunk_bytes=64 << 20):
     """Fill a (possibly huge) array chunk-wise: same result as a full
@@ -405,7 +369,7 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
     from .uniform import _NeighborMaps
     from . import native
 
-    mark = _phase_timer()
+    mark = telemetry.phase_timer()  # classify, tables
     if arena is None:
         arena = PlanArena()
         arena.begin()
@@ -480,7 +444,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
     hard_pos = np.concatenate(hard_parts)
     hard_pos.sort(kind="stable")
     hard_cells = cells[hard_pos]
-    mark(f"classify (hard {len(hard_pos)}/{n})")
     faults.fire("hybrid.recommit", phase="classified")
 
     # --- hard streams (generic engine on the hard shell) --------------
@@ -599,8 +562,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
     # the reuse cache was just swapped IN PLACE: a fault here pins that
     # the transaction snapshot restores its previous contents too
     faults.fire("hybrid.recommit", phase="cached")
-    mark(f"hard streams (reused {0 if reusable is None else len(reusable)}"
-         f"/{len(hard_cells)})")
 
     # --- boundary classification + ghost sets -------------------------
     # every cross-device of-edge (c -> v) makes both endpoints outer
@@ -641,7 +602,7 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
             cm = np.nonzero(owner[s_p] != owner[s_n])[0]
             if len(cm):
                 note_cross(s_p[cm], s_n[cm], default)
-    mark("classification")
+    mark("classify")
     g_r = np.concatenate(ghost_reader)
     g_p = np.concatenate(ghost_pos)
 
@@ -714,7 +675,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
         ridx = owner[blk.a + ei].astype(np.int64) * L + row_of_pos[blk.a + ei]
         easy_rowidx[blk.level] = (ei, ridx)
         scale_rows[ridx] = blk.size
-    mark("row layout")
 
     # --- gather tables per hood (split far+easy / hard) ---------------
     hood_data = {}
@@ -758,7 +718,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
                 nslot = (-2 - rows_t[far_rowidx[ci], cj]).astype(np.int64)
                 rows_t[far_rowidx[ci], cj] = resolve_rows(
                     pos0[nslot], far_dev[ci])
-            mark(f"tables[{hid}]: far direct ({len(fix)} fixups)")
         else:
             fr = np.empty((len(far_slots), k), dtype=np.int32)
             fm = np.empty((len(far_slots), k), dtype=bool)
@@ -775,7 +734,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
             rows_t[far_rowidx] = fr
             mask_t[far_rowidx] = fm
             del fr, fm
-            mark(f"tables[{hid}]: far scatter")
 
         # easy rows: level-l index arithmetic, all offsets batched
         for blk, easy in blocks:
@@ -798,8 +756,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
                     p = (-2 - rows_t[ridx[ce], cj]).astype(np.int64)
                     rows_t[ridx[ce], cj] = resolve_rows(
                         p, owner[blk.a + ei[ce]].astype(np.int64))
-                mark(f"tables[{hid}]: easy block l{blk.level} "
-                     f"({len(fix)} fixups)")
                 continue
             edev = owner[blk.a + ei].astype(np.int64)
             posm = np.empty((E, k), dtype=np.int64)
@@ -816,7 +772,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
                 )
             rows_t[ridx] = rows.reshape(E, k)
             mask_t[ridx] = validm
-            mark(f"tables[{hid}]: easy block l{blk.level}")
 
         # hard rows: compact per-device tables from the stream
         hard_rows_dev = hard_nbr_dev = hard_offs_dev = hard_mask_dev = None
@@ -829,8 +784,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
                 s_p, owner if n_dev > 1 else None, n_dev)
             S_hard = cap(("S_hard", hid), max(1, int(s_need)))
             Hmax = cap(("Hmax", hid), max(1, int(counts.max())))
-            mark(f"tables[{hid}]: hard grouping (H {int(counts.max())}"
-                 f"/{Hmax}, S {int(s_need)}/{S_hard})")
             hard_rows_dev = arena.take((n_dev, Hmax), np.int32)
             hard_nbr_dev = arena.take((n_dev, Hmax, S_hard), np.int32)
             hard_offs_dev = arena.take((n_dev, Hmax, S_hard, 3), np.int32)
@@ -845,7 +798,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
                 rdev = fix // (Hmax * S_hard)  # reader device of the entry
                 p = (-2 - flat[fix]).astype(np.int64)
                 flat[fix] = resolve_rows(p, rdev)
-            mark(f"tables[{hid}]: hard assembly ({len(fix)} fixups)")
         elif nE:
             # slot = rank within the (contiguous, source-sorted) group
             changed = np.empty(nE, dtype=bool)
@@ -885,7 +837,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
             hard_nbr_dev[e_dev, e_pos, slot] = resolve_rows(s_n, owner[s_p])
             hard_offs_dev[e_dev, e_pos, slot] = s_off.astype(np.int32)
             hard_mask_dev[e_dev, e_pos, slot] = True
-            mark(f"tables[{hid}]: hard assembly")
 
         offs_const = offs.astype(np.int32)  # [k, 3], CELL units (x scale_rows)
 
@@ -908,7 +859,6 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
             "hard_offs": hard_offs_dev,
             "hard_mask": hard_mask_dev,
         }
-        mark(f"tables hood {hid}")
 
     # arena tables are all written at this point: a fault here pins
     # that a rolled-back plan's (protected) buffers were never touched
@@ -926,7 +876,7 @@ def build_hybrid_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
     )
     for hid in neighborhoods:
         hood_data[hid]["pair_compact"] = pair_compact
-    mark("send/recv lists")
+    mark("tables")
 
     # --- lazy neighbors_to tables -------------------------------------
     is_hard_target = np.zeros(n, dtype=bool)

@@ -16,9 +16,8 @@ That split is exactly what the per-field ghost-split overlap
 (``DCCRG_GHOST_SPLIT``, grid.py) consumes: each pass declares
 ``ghost_deps`` and exchanges only its own subsystem, so the overlap
 outer re-pass recomputes the subsystem's rows instead of every outer
-row x every field (counted by ``Grid.last_overlap`` /
-``dccrg_outer_repass_rows_total``; bench/models_bench.py's
-``outer_repass_rows_{full,split}`` keys).
+row x every field (counted by ``Grid.last_overlap``, which
+bench/models_bench.py's ``outer_repass_rows_{full,split}`` keys read).
 
 Modeling notes (honest simplifications):
 

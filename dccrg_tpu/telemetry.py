@@ -44,11 +44,8 @@ step-loop blockage recorded in the ``dccrg_recommit_stall_seconds``
 ``dccrg_ckpt_stall_seconds`` histograms — the serving-path stall a
 sync epoch would have charged in full, so the sync-vs-background win
 is one PromQL ratio (``bench/recommit_bench.py --overlap`` measures
-the same quantity offline). The per-field ghost split counts its
-outer re-pass row slots in ``dccrg_outer_repass_rows_total{mode}``
-(vs ``dccrg_outer_repass_rows_full_total``, the full-re-pass
-baseline), and the mixed-kernel lane SLO shed marks each parked
-cohabitant in ``dccrg_fleet_lane_sheds_total{job}``. The warm-start
+the same quantity offline). The mixed-kernel lane SLO shed marks each
+parked cohabitant in ``dccrg_fleet_lane_sheds_total{job}``. The warm-start
 layer (warmstart.py) counts pool-served vs compiled first dispatches
 in ``dccrg_warm_hits_total`` / ``dccrg_warm_misses_total`` (the
 ``where=aot_fallback`` series marks an AOT executable that declined
@@ -63,6 +60,18 @@ in ``dccrg_warm_quarantined_total`` with typed degradations in
 to the first dispatch actually served in the
 ``dccrg_warm_first_dispatch_ready_seconds`` gauge — the rejoin
 latency the mp harness's ``rejoin_warm`` scenario bounds.
+
+**Profiler clock** — while a ``jax.profiler`` session records,
+every span also enters a ``jax.profiler.TraceAnnotation`` of its name,
+``DCCRG_TRACE`` or not, so ``grid.step``, ``grid.exchange*`` and the
+rest land on the trace's ``/host:CPU`` plane on the device ops' clock.
+The step program (``Grid.compile_step_loop``) wraps its phases in
+``jax.named_scope("dccrg.<phase>")``; the first ``run_steps`` call of a
+program under a session publishes the program's op -> phase table
+(:func:`publish_scopes`, read back with :func:`program_scopes`), which
+labels the ops of a device trace by phase. Plan builds mark their
+phases (:func:`phase_timer`) in the always-on gauge
+``dccrg_plan_phase_seconds{phase}``.
 
 **Trace export** — :func:`flush_trace` appends the ring as JSONL (one
 event per line) to ``DCCRG_TRACE_FILE`` (auto-flushed at process
@@ -96,8 +105,10 @@ import json
 import math
 import os
 import re
+import sys
 import threading
 import time
+import weakref
 
 from . import faults
 
@@ -253,6 +264,10 @@ class Registry:
     def counter_value(self, name: str, **labels):
         return self.counters.get(_key(name, labels), 0)
 
+    def gauge_value(self, name: str, **labels):
+        """The gauge's value, or None where it was never set."""
+        return self.gauges.get(_key(name, labels))
+
     def counter_total(self, name: str, **labels) -> float:
         """Sum of every series of ``name`` whose labels include the
         given ones (e.g. all ``kind=...`` series of one job)."""
@@ -375,7 +390,8 @@ class _NullSpan:
         return False
 
 
-_NULL_SPAN = _NullSpan()
+#: what :func:`span` returns with tracing off and no profiler session
+NULL_SPAN = _NullSpan()
 
 #: tracing state, mutable at runtime via :func:`configure`. A dict so
 #: instrumented modules can ``from . import telemetry`` once and still
@@ -439,8 +455,25 @@ def _ring_append(ev) -> None:
     ring.append(ev)
 
 
+_PROFILER = [None]  # jax.profiler.TraceAnnotation, bound once jax is imported
+
+
+def profiling() -> bool:
+    """True while a ``jax.profiler`` session records (one
+    ``TraceAnnotation.is_enabled()`` call). Never imports jax itself: a
+    process that has not imported it records no profile."""
+    ann = _PROFILER[0]
+    if ann is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        ann = _PROFILER[0] = TraceAnnotation
+    return ann.is_enabled()
+
+
 class _Span:
-    __slots__ = ("name", "tags", "t_wall", "t0")
+    __slots__ = ("name", "tags", "t_wall", "t0", "ann")
 
     def __init__(self, name, tags):
         self.name = name
@@ -448,12 +481,17 @@ class _Span:
 
     def __enter__(self):
         _stack().append(self.name)
+        self.ann = _PROFILER[0](self.name) if profiling() else None
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t_wall = time.time()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         stack = _stack()
         stack.pop()
         ev = {
@@ -477,13 +515,17 @@ class _Span:
 def span(name: str, tags: "dict | None" = None):
     """A tracing span: ``with telemetry.span("grid.step"): ...``
     records one ring event (name, wall-clock anchor, monotonic
-    duration, rank, nesting depth/parent, tags) on exit. With tracing
-    off this returns the shared no-op singleton — the hot-path
-    contract is ONE dict lookup and no allocation, so instrumented
-    step paths cost nothing in production. ``tags`` is an optional
-    plain dict (not kwargs, so the off path never builds one)."""
+    duration, rank, nesting depth/parent, tags) on exit. While a
+    ``jax.profiler`` session records, the span is also a
+    ``TraceAnnotation`` of ``name`` on the profiler's clock, with or
+    without ``DCCRG_TRACE``. With tracing off and no session this
+    returns the shared no-op singleton — the hot-path contract is one
+    dict lookup, one ``is_enabled()`` check and no allocation, so
+    instrumented step paths cost nothing in production. ``tags`` is an
+    optional plain dict (not kwargs, so the off path never builds
+    one)."""
     if not _TRACE["on"]:
-        return _NULL_SPAN
+        return _PROFILER[0](name) if profiling() else NULL_SPAN
     return _Span(name, tags)
 
 
@@ -525,10 +567,10 @@ class _TagScope:
 def traced(name: str, tags: "dict | None" = None,
            counter: "str | None" = None):
     """Decorator form of :func:`span` for whole-function boundaries
-    (checkpoint save/load/GC phases). With tracing off the wrapper is
-    one dict lookup and a tail call. ``counter`` additionally bumps a
-    registry counter on every call, traced or not (the metrics side
-    is always on)."""
+    (checkpoint save/load/GC phases). With tracing off and no profiler
+    session the wrapper is one dict lookup, one ``is_enabled()`` check
+    and a tail call. ``counter`` additionally bumps a registry counter
+    on every call, traced or not (the metrics side is always on)."""
     import functools
 
     def deco(fn):
@@ -536,9 +578,9 @@ def traced(name: str, tags: "dict | None" = None,
         def wrapper(*a, **kw):
             if counter is not None:
                 _REGISTRY.inc(counter)
-            if not _TRACE["on"]:
+            if not _TRACE["on"] and not profiling():
                 return fn(*a, **kw)
-            with _Span(name, tags):
+            with span(name, tags):
                 return fn(*a, **kw)
         return wrapper
     return deco
@@ -549,7 +591,7 @@ def tags(**kv):
     inside the context (the fleet layer tags checkpoint saves with the
     owning ``job=``). No-op singleton with tracing off."""
     if not _TRACE["on"]:
-        return _NULL_SPAN
+        return NULL_SPAN
     return _TagScope(kv)
 
 
@@ -576,6 +618,90 @@ def configure(trace=None, ring=None) -> None:
                                            maxlen=max(16, int(ring)))
     _TRACE["on"] = (trace_enabled_default() if trace is None
                     else bool(trace))
+
+
+# ---------------------------------------------------------------------
+# plan-build phases and the step program's phase table
+# ---------------------------------------------------------------------
+
+PLAN_PHASE_GAUGE = "dccrg_plan_phase_seconds"
+
+
+def phase_timer():
+    """Plan-build phase marks: ``mark(label)`` closes the phase that ran
+    since the previous mark (or since this call). Always on (builds are
+    rare): a label's seconds, summed over this timer's marks, set
+    ``dccrg_plan_phase_seconds{phase=label}``, so each phase reads the
+    newest build that ran it. With ``DCCRG_TIMING=1`` each mark prints
+    ``[plan] label: <s>``; with tracing on it records the ring span
+    ``plan.label``."""
+    echo = os.environ.get("DCCRG_TIMING") == "1"
+    totals: dict = {}
+    state = [time.perf_counter()]
+
+    def mark(label: str) -> None:
+        now = time.perf_counter()
+        dt = now - state[0]
+        state[0] = now
+        totals[label] = totals.get(label, 0.0) + dt
+        _REGISTRY.set_gauge(PLAN_PHASE_GAUGE, totals[label], phase=label)
+        if echo:
+            print(f"[plan] {label}: {dt:.3f}s", flush=True)
+        record_span(f"plan.{label}", dt)
+
+    return mark
+
+
+SCOPE_PREFIX = "dccrg."
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+)\s*=")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_MODULE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+
+#: {HLO module name: {instruction name: "dccrg.<phase>" | "unscoped"}}
+_SCOPES: dict = {}
+_PUBLISHED = weakref.WeakSet()  # the programs whose table is in _SCOPES
+
+
+def scope_table(hlo_text: str) -> dict:
+    """``{instruction name: scope}`` for every instruction of a compiled
+    module's text: the outermost ``dccrg.*`` component of its
+    ``op_name`` metadata, or ``"unscoped"``. A fusion carries its
+    root's ``op_name``. Names drop the ``%``, as a profiler trace shows
+    them."""
+    table = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME.search(line)
+        comps = op.group(1).split("/") if op else ()
+        table[m.group(1)] = next(
+            (c for c in comps if c.startswith(SCOPE_PREFIX)), "unscoped")
+    return table
+
+
+def publish_scopes(fn, args) -> None:
+    """Publish the phase table of the jitted ``fn`` at ``args`` under
+    its module's name, once per program: ``fn.lower(*args).compile()``
+    after a call with the same arguments is a compile-cache hit. The
+    seconds it took go to ``dccrg_scope_table_seconds{module}``. Step
+    programs share one module name: the newest one published holds it."""
+    if fn in _PUBLISHED:
+        return
+    _PUBLISHED.add(fn)
+    t0 = time.perf_counter()
+    text = fn.lower(*args).compile().as_text()
+    module = _MODULE.search(text)
+    name = module.group(1) if module else "unknown"
+    _SCOPES[name] = scope_table(text)
+    _REGISTRY.set_gauge("dccrg_scope_table_seconds",
+                        time.perf_counter() - t0, module=name)
+
+
+def program_scopes() -> dict:
+    """``{module name: {op name: scope}}`` of every step program
+    published under a profiler session (see :func:`publish_scopes`)."""
+    return {k: dict(v) for k, v in _SCOPES.items()}
 
 
 # ---------------------------------------------------------------------

@@ -34,6 +34,8 @@ import os
 
 import numpy as np
 
+from . import telemetry
+
 
 def is_uniform(cells: np.ndarray, n0: int) -> bool:
     """True when ``cells`` is exactly the full level-0 cell set 1..n0."""
@@ -339,13 +341,16 @@ def build_uniform_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
 
     hoods = {hid: np.asarray(offs, dtype=np.int64).reshape(-1, 3)
              for hid, offs in neighborhoods.items()}
+    mark = telemetry.phase_timer()
 
     if n_dev == 1 and os.environ.get("DCCRG_FORCE_TABLES") != "1":
         # closed-form: no lattice map, no tables (DCCRG_FORCE_TABLES=1
         # falls through to the dense builder — the bench's roll-vs-
         # table A/B leg and the cross-check path)
-        return _build_single_device_plan(
+        out = _build_single_device_plan(
             mapping, hoods, cells, dims, periodic, size, cap)
+        mark("tables")
+        return out
 
     maps = _NeighborMaps(dims, periodic)
 
@@ -389,6 +394,7 @@ def build_uniform_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
         gg = np.unique(gnbr[gdev == d]) if n_dev > 1 else np.empty(0, np.int32)
         ghost_gidx.append(gg.astype(np.int64))
         ghost_ids.append((gg.astype(np.uint64) + 1))
+    mark("classify")
 
     from .grid import bucket_capacity
 
@@ -536,6 +542,7 @@ def build_uniform_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
             local_ids=local_ids, ghost_ids=ghost_ids, n_local=n_local,
             n_inner=n_inner, L=L, R=R, row_of_pos=row_of_pos,
         )
+        mark("tables")
         return layout, hood_data
 
     hood_data = {}
@@ -579,6 +586,7 @@ def build_uniform_plan(mapping, topology, neighborhoods, cells, owner, n_dev,
         local_ids=local_ids, ghost_ids=ghost_ids, n_local=n_local,
         n_inner=n_inner, L=L, R=R, row_of_pos=row_of_pos,
     )
+    mark("tables")
     return layout, hood_data
 
 

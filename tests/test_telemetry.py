@@ -149,6 +149,32 @@ def test_noop_mode_is_singleton_and_zero_allocation():
     assert telemetry.events() == []
 
 
+def test_span_lands_on_profiler_host_plane(tmp_path):
+    """DCCRG_TRACE off, a jax.profiler session on: the span is a
+    TraceAnnotation on the trace's host plane, and the ring stays
+    empty."""
+    import glob
+
+    import jax
+
+    assert not telemetry.trace_enabled()
+    assert not telemetry.profiling()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert telemetry.profiling()
+        with telemetry.span("grid.step"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    assert not telemetry.profiling()
+    assert telemetry.events() == []
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = [e for p in pd.planes if p.name == "/host:CPU"
+            for line in p.lines for e in line.events if e.name == "grid.step"]
+    assert len(host) == 1 and host[0].duration_ns >= 2e6
+
+
 def test_record_span_and_traced_decorator():
     telemetry.configure(trace=True)
     telemetry.record_span("hybrid.classification", 0.125, {"n": 2})
