@@ -289,6 +289,121 @@ def _make_roll3d_gather(synth, L):
     return gather
 
 
+def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
+    """Host check and tables for the slab gather (_make_slab3d_gather)
+    of a multi-device closed-form plan. Engages when every device holds
+    the same number ``Zs`` of whole z planes and its local and ghost
+    rows, read ``nx*ny`` at a time, are whole planes in id order.
+
+    Returns ``((Zs, z_offsets, W), fix)`` or None. For z offset
+    ``z_offsets[k]`` the slot column starts as the local rows rolled by
+    whole planes, which is right wherever the row planes ``[inner |
+    outer]`` continue in grid order; ``fix[d, k, w] = (dst, src)``
+    (first rows) copies the true neighbor plane over each of the few
+    planes where they do not (slab ends, ghost planes). Pad entries
+    repeat a real entry, or rewrite a plane with its own rolled value,
+    so every entry is idempotent. Planes beyond a walled z end are left
+    alone: the slot mask zeroes them. O(planes): inner and outer rows
+    are each in id order and ghost ids are sorted, so a chunk whose
+    first and last ids bound one plane is that plane."""
+    (nx, ny, nz), per_z = cf["dims"], cf["periodic"][2]
+    nxy = nx * ny
+    n_loc = {len(ids) for ids in local_ids}
+    if len(n_loc) != 1 or n_inner is None:
+        return None
+    Zs, rem = divmod(n_loc.pop(), nxy)
+    if Zs == 0 or rem or any(int(n) % nxy for n in n_inner):
+        return None
+
+    def planes(ids):
+        if len(ids) % nxy:
+            return None
+        first = ids[::nxy].astype(np.int64) - 1
+        last = ids[nxy - 1::nxy].astype(np.int64) - 1
+        if (first % nxy).any() or (last - first != nxy - 1).any():
+            return None
+        return first // nxy
+
+    z_offsets = tuple(sorted({int(o[2]) for o in cf["offsets"]} - {0}))
+    fixes = []  # [device][k] -> [(dst, src), ...]
+    for lids, gids in zip(local_ids, ghost_ids):
+        zl, zg = planes(lids), planes(gids)
+        if zl is None or zg is None or not np.array_equal(
+                np.sort(zl), np.arange(zl.min(), zl.min() + Zs)):
+            return None
+        first_row = np.full(nz, -1, np.int64)
+        first_row[zg] = L + nxy * np.arange(len(zg))
+        first_row[zl] = nxy * np.arange(Zs)
+        per_k = []
+        for oz in z_offsets:
+            rolled = ((np.arange(Zs) + oz) % Zs) * nxy
+            tz = zl + oz
+            if per_z:
+                tz %= nz
+            inside = (tz >= 0) & (tz < nz)
+            src = np.where(inside, first_row[np.clip(tz, 0, nz - 1)], rolled)
+            if (src < 0).any():
+                return None  # a neighbor plane this device does not hold
+            wrong = np.flatnonzero(src != rolled)
+            per_k.append([(r * nxy, src[r]) for r in wrong]
+                         or [(0, rolled[0])])
+        fixes.append(per_k)
+    W = max(len(f) for per_k in fixes for f in per_k) if z_offsets else 1
+    fix = np.zeros((len(local_ids), max(len(z_offsets), 1), W, 2), np.int32)
+    for d, per_k in enumerate(fixes):
+        for k, f in enumerate(per_k):
+            fix[d, k] = f + [f[0]] * (W - len(f))
+    return (Zs, z_offsets, W), fix
+
+
+def _make_slab3d_gather(synth, L, slab, fix):
+    """Multi-device closed-form slot gather for plans whose rows are
+    whole z planes (_plane_slabs). A z offset rolls the local rows by
+    whole planes and copies the true neighbor plane over the few planes
+    where the ``[inner | outer]`` row order breaks the shift (``fix``
+    [K, W, 2] first rows); x/y offsets are exact periodic ``jnp.roll``
+    on the plane axes. Only whole-plane slices, concatenations and
+    copies: no per-element gather or scatter, unlike the flat roll's
+    fixups. Masked slots are zeroed like _make_roll3d_gather."""
+    (nx, ny, _nz), _per, _n0, offs_cells, *_ = synth
+    Zs, z_offsets, W = slab
+    nxy = nx * ny
+    n = Zs * nxy
+
+    def gather(fl, j, mask_j):
+        ox, oy, oz = offs_cells[j]
+        trail = fl.shape[1:]
+        col = fl[:n]
+        if oz != 0:
+            col = jnp.roll(col, -oz * nxy, axis=0)
+            fk = fix[z_offsets.index(oz)]
+            for w in range(W):
+                col = jax.lax.dynamic_update_slice_in_dim(
+                    col, jax.lax.dynamic_slice_in_dim(fl, fk[w, 1], nxy),
+                    fk[w, 0], 0)
+        if ox or oy:
+            col = jnp.roll(col.reshape((Zs, ny, nx) + trail),
+                           shift=(-oy, -ox), axis=(1, 2))
+            col = col.reshape((n,) + trail)
+        if L > n:
+            col = jnp.pad(col, [(0, L - n)] + [(0, 0)] * len(trail))
+        mexp = mask_j.reshape(mask_j.shape + (1,) * len(trail))
+        return jnp.where(mexp, col, jnp.zeros((), col.dtype))
+
+    return gather
+
+
+def _make_slot_gather(kind, synth, L, use_roll, r_shifts, nrows, wr, ws,
+                      slab, fix):
+    """The slot gather of ``kind`` (Grid._slot_gather_kind), shared by
+    apply_stencil and the fused step loop."""
+    if kind == "roll3d":
+        return _make_roll3d_gather(synth, L)
+    if kind == "slab3d":
+        return _make_slab3d_gather(synth, L, slab, fix)
+    return _make_nbr_slot_gather(use_roll, r_shifts, L, nrows, wr, ws)
+
+
 def _make_offs_col(uniform_offs, noffs, sc0):
     """Per-slot offsets closure shared by the stencil bodies and the
     dense adapter: raw (NOT premasked — kernels gate on the mask),
@@ -472,6 +587,7 @@ class _HoodPlan:
             to_tables = (to_rows, to_offs, to_mask)
         self._to = to_tables  # (rows, offs, mask) or thunk
         self._roll_plan = None  # computed on demand by roll_plan()
+        self._slab = None  # computed on demand by slab_plan()
         # per-epoch memo of device uploads (tables as jit ARGUMENTS:
         # programs are shape-keyed and reused across structure epochs,
         # only the table values re-upload)
@@ -582,6 +698,15 @@ class _HoodPlan:
                 wrong_src[d, j, : len(w)] = rows[d, w, j]
         self._roll_plan = (shifts, wrong_rows, wrong_src)
         return self._roll_plan
+
+    def slab_plan(self, local_ids, ghost_ids, L):
+        """``((Zs, z_offsets, W), fix)`` of the slab gather for a
+        multi-device closed-form plan whose rows are whole z planes, or
+        None (see _plane_slabs). Computed once per structure epoch."""
+        if self._slab is None:
+            self._slab = _plane_slabs(self.closed_form, local_ids,
+                                      ghost_ids, self.n_inner, L) or ()
+        return self._slab or None
 
     def merged_of_tables(self, pad_row):
         """Dense [n_dev, L, S] (rows, offs, mask) merging the far and
@@ -2451,6 +2576,22 @@ class Grid:
             return env == "1"
         return self._on_accelerator()
 
+    def _slot_gather_kind(self, hood, cf, use_roll):
+        """``(kind, slab)`` of a slot-wise stencil's neighbor gather, by
+        the layout the plan has: ``"roll3d"`` (single-device closed
+        form: rows are grid order), ``"slab3d"`` (multi-device closed
+        form whose rows are whole z planes; ``slab`` is its
+        ``hood.slab_plan``), ``"roll_fixup"`` (flat roll + fixup
+        scatter) or ``"table"``."""
+        if cf is not None and not cf.get("multi"):
+            return "roll3d", None
+        if cf is not None:
+            plan = self.plan
+            slab = hood.slab_plan(plan.local_ids, plan.ghost_ids, plan.L)
+            if slab is not None:
+                return "slab3d", slab
+        return ("roll_fixup" if use_roll else "table"), None
+
     def _use_overlap(self) -> bool:
         """Overlapped fused steps: start the halo collectives, run the
         bulk kernel on pre-exchange state (inner rows' results are
@@ -2774,7 +2915,13 @@ class Grid:
             else:
                 tables.append(hood.dev("nbr_mask", hood.nbr_mask, sh))
         r_shifts = tuple(int(s) for s in roll[0]) if roll is not None else None
-        if roll is not None:
+        slotwise = isinstance(kernel, SlotwiseKernel)
+        g_kind, slab = (self._slot_gather_kind(hood, cf, roll is not None)
+                        if slotwise else (None, None))
+        if slab is not None:
+            slab, fix = slab  # static (Zs, z offsets, W); device table
+            tables.append(hood.dev("slab_fix", fix, sh))
+        elif roll is not None:
             tables.append(hood.dev("roll_wr", roll[1], sh))
             tables.append(hood.dev("roll_ws", roll[2], sh))
         scaled = uniform_offs and hood.scale_rows is not None
@@ -2792,7 +2939,8 @@ class Grid:
 
         synth = _synth_key(cf)
         key = ("stencil", kernel, fields_in, fields_out, include_to, n_extra,
-               L, R, uniform_offs, scaled, split, merged, r_shifts, synth)
+               L, R, uniform_offs, scaled, split, merged, r_shifts, synth,
+               slab)
         fn = self._program_cache.get(key)
         if fn is not None:
             return fn, tables
@@ -2800,9 +2948,10 @@ class Grid:
         n_in, n_out = len(fields_in), len(fields_out)
         axis, mesh = self.axis, self.mesh
         use_roll = r_shifts is not None
-        if isinstance(kernel, SlotwiseKernel) and include_to:
+        if slotwise and include_to:
             raise ValueError("SlotwiseKernel does not support include_to")
-        slotwise = isinstance(kernel, SlotwiseKernel)
+        if slotwise:
+            telemetry.inc("dccrg_slot_gather_programs_total", gather=g_kind)
 
         def body(nrows, noffs, nmask, *args):
             nrows = nrows[0]
@@ -2812,7 +2961,11 @@ class Grid:
                 nmask = None  # synthesized on demand (dense) / per-slot
             else:
                 nmask = nmask[0]
-            if use_roll:
+            wr = ws = slab_fix = None
+            if slab:
+                slab_fix, *args = args
+                slab_fix = slab_fix[0]
+            elif use_roll:
                 wr, ws, *args = args
                 wr, ws = wr[0], ws[0]
             if scaled:
@@ -2839,13 +2992,9 @@ class Grid:
                 else:
                     mask_col = lambda j: nmask[:, j]
                 n_slots = len(r_shifts) if use_roll else nrows.shape[1]
-                if synth is not None and not synth[4]:
-                    slot_gather = _make_roll3d_gather(synth, L)
-                else:
-                    slot_gather = _make_nbr_slot_gather(
-                        use_roll, r_shifts, L, nrows,
-                        wr if use_roll else None, ws if use_roll else None,
-                    )
+                slot_gather = _make_slot_gather(
+                    g_kind, synth, L, use_roll, r_shifts, nrows, wr, ws,
+                    slab, slab_fix)
                 result = _run_slotwise(
                     kernel, cell_fields,
                     {n: f[0] for n, f in zip(fields_in, ins)}, slot_gather,
@@ -2862,9 +3011,7 @@ class Grid:
                         # size
                         noffs = noffs * sc0[:, None, None]
                 gather_nbr = _make_nbr_gather(
-                    use_roll, r_shifts, L, nrows, nmask,
-                    wr if use_roll else None, ws if use_roll else None,
-                )
+                    use_roll, r_shifts, L, nrows, nmask, wr, ws)
                 nbr_fields = {n: gather_nbr(f[0])
                               for n, f in zip(fields_in, ins)}
                 if include_to:
@@ -2900,7 +3047,7 @@ class Grid:
             body,
             mesh=mesh,
             in_specs=(P(axis), P() if uniform_offs else P(axis), P(axis))
-            + ((P(axis), P(axis)) if use_roll else ())
+            + ((P(axis),) if slab else (P(axis), P(axis)) if use_roll else ())
             + ((P(axis),) if scaled else ())
             + ((P(axis),) * 4 if split else ())
             + ((P(axis), P(axis), P(axis)) if include_to else ())
@@ -3016,7 +3163,13 @@ class Grid:
         n_t = 1 if deltas is None else len(deltas)
         tables.extend(sends)
         tables.extend(recvs)
-        if use_roll:
+        slotwise = isinstance(kernel, SlotwiseKernel)
+        g_kind, slab = (self._slot_gather_kind(hood, cf, use_roll)
+                        if slotwise else (None, None))
+        if slab is not None:
+            slab, fix = slab  # static (Zs, z offsets, W); device table
+            tables.append(hood.dev("slab_fix", fix, sh))
+        elif use_roll:
             tables.append(hood.dev("roll_wr", roll[1], sh))
             tables.append(hood.dev("roll_ws", roll[2], sh))
         scaled = uniform_offs and hood.scale_rows is not None
@@ -3098,14 +3251,15 @@ class Grid:
         synth = _synth_key(cf)
         key = ("steploop", kernel, fields_in, fields_out, exch_idx, n_extra,
                L, R, uniform_offs, scaled, split, r_shifts, synth, deltas,
-               overlap) + ((("gsplit", o_mode, repass),)
-                           if o_mode in ("split", "none") else ())
+               overlap, slab) + ((("gsplit", o_mode, repass),)
+                                 if o_mode in ("split", "none") else ())
         fn = self._program_cache.get(key)
         if fn is not None:
             return fn, tables, static_in
 
         axis, mesh, n_dev = self.axis, self.mesh, self.n_dev
-        slotwise = isinstance(kernel, SlotwiseKernel)
+        if slotwise:
+            telemetry.inc("dccrg_slot_gather_programs_total", gather=g_kind)
 
         def body(n_steps, nrows, noffs, nmask, *args):
             send_rs = [a[0] for a in args[: n_x * n_t]]
@@ -3118,7 +3272,11 @@ class Grid:
                 nmask = None  # synthesized on demand (dense) / per-slot
             else:
                 nmask = nmask[0]
-            if use_roll:
+            wr = ws = slab_fix = None
+            if slab:
+                slab_fix, *args = args
+                slab_fix = slab_fix[0]
+            elif use_roll:
                 wr, ws, *args = args
                 wr, ws = wr[0], ws[0]
             if scaled:
@@ -3157,13 +3315,9 @@ class Grid:
                 else:
                     mask_col = lambda j: nmask[:, j]
                     mask_rows = lambda rows: nmask[rows]
-                if synth is not None and not synth[4]:
-                    slot_gather = _make_roll3d_gather(synth, L)
-                else:
-                    slot_gather = _make_nbr_slot_gather(
-                        use_roll, r_shifts, L, nrows,
-                        wr if use_roll else None, ws if use_roll else None,
-                    )
+                slot_gather = _make_slot_gather(
+                    g_kind, synth, L, use_roll, r_shifts, nrows, wr, ws,
+                    slab, slab_fix)
 
                 def offs_rows(rows, m):
                     # dense offsets for a surface-sized row subset,
@@ -3190,9 +3344,7 @@ class Grid:
                     if scaled:
                         noffs = noffs * sc0[:, None, None]
                 gather_nbr = _make_nbr_gather(
-                    use_roll, r_shifts, L, nrows, nmask,
-                    wr if use_roll else None, ws if use_roll else None,
-                )
+                    use_roll, r_shifts, L, nrows, nmask, wr, ws)
 
             statics = {n: a[0] for n, a in zip(static_in, args[:n_static])}
             state0 = tuple(a[0] for a in args[n_static:n_static + n_out])
@@ -3312,7 +3464,7 @@ class Grid:
             in_specs=(P(), P(axis),
                       P() if uniform_offs else P(axis), P(axis))
             + (P(axis),) * (2 * n_x * n_t)
-            + ((P(axis), P(axis)) if use_roll else ())
+            + ((P(axis),) if slab else (P(axis), P(axis)) if use_roll else ())
             + ((P(axis),) if scaled else ())
             + ((P(axis),) * 4 if split else ())
             + ((P(axis), P(axis)) if o_tabs else ())

@@ -140,3 +140,14 @@ def test_xla_step_program_compiles_128(topo):
     compiled = advection_step_128(topo.devices[:1])
     assert "tpu_custom_call" not in compiled.as_text()
     assert fits_hbm(compiled)
+
+
+def test_xla_step_program_compiles_128_mesh4(topo):
+    """The step program on a 2x2 v5e: four z slabs of whole planes take
+    the slab gather, so the bulk pass holds no scatter (the flat roll's
+    fix-ups were one per slot and field)."""
+    compiled = advection_step_128(topo.devices[:4])
+    assert fits_hbm(compiled)
+    bulk_scatters = [ln for ln in compiled.as_text().splitlines()
+                     if " scatter(" in ln and "dccrg.bulk" in ln]
+    assert not bulk_scatters

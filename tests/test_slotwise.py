@@ -212,3 +212,129 @@ def test_slotwise_include_to_raises(monkeypatch):
     g = _mk(monkeypatch, roll=False)
     with pytest.raises(ValueError, match="include_to"):
         g.apply_stencil(_slot_kern(), ["v", "w"], ["v"], include_to=True)
+
+
+def _mesh_grid(n_dev, length, periodic, payload):
+    import jax
+    from jax.sharding import Mesh
+
+    cell_data = {"v": jnp.float32, "w": jnp.float32}
+    if payload:
+        cell_data["p"] = ((2,), jnp.float32)
+    g = (
+        Grid(cell_data=cell_data)
+        .set_initial_length(length)
+        .set_periodic(*periodic)
+        .set_maximum_refinement_level(0)
+        .set_neighborhood_length(1)
+        .initialize(Mesh(np.array(jax.devices()[:n_dev]), ("dev",)),
+                    partition="block")
+    )
+    cells = g.plan.cells
+    rng = np.random.default_rng(7)
+    g.set("v", cells, rng.integers(0, 64, len(cells)).astype(np.float32))
+    g.set("w", cells, rng.integers(0, 64, len(cells)).astype(np.float32))
+    if payload:
+        g.set("p", cells,
+              rng.integers(0, 64, (len(cells), 2)).astype(np.float32))
+    g.update_copies_of_remote_neighbors()
+    return g
+
+
+def _xyz_slot_kern(payload):
+    """Integer-valued and bounded (mod 1021): each of the 26 slots gets
+    its own weight from its x, y AND z offset, so a neighbor taken from
+    the wrong plane, row or slot changes the result exactly."""
+    def init(cell, *extra):
+        acc = {"v": jnp.zeros(cell["v"].shape, jnp.float32)}
+        if payload:
+            acc["p"] = jnp.zeros(cell["p"].shape, jnp.float32)
+        return acc
+
+    def slot(acc, cell, nbr, offs, mask, *extra):
+        wgt = (1 + (offs[..., 0] + 1) + 3 * (offs[..., 1] + 1)
+               + 9 * (offs[..., 2] + 1)).astype(jnp.float32)
+        acc = dict(acc)
+        acc["v"] = acc["v"] + jnp.where(mask, wgt * nbr["v"] + nbr["w"], 0.0)
+        if payload:
+            acc["p"] = acc["p"] + jnp.where(
+                mask[:, None], wgt[..., None] * nbr["p"], 0.0)
+        return acc
+
+    def finish(acc, cell, *extra):
+        out = {"v": jnp.mod(cell["v"] + acc["v"], 1021.0)}
+        if payload:
+            out["p"] = jnp.mod(cell["p"] + acc["p"], 1021.0)
+        return out
+
+    return SlotwiseKernel(init, slot, finish)
+
+
+def _stepped(monkeypatch, n_dev, length, periodic, overlap, payload, op):
+    from dccrg_tpu import telemetry
+
+    monkeypatch.setenv("DCCRG_OVERLAP", "1" if overlap else "0")
+    g = _mesh_grid(n_dev, length, periodic, payload)
+    ins = ("v", "w") + (("p",) if payload else ())
+    outs = ("v",) + (("p",) if payload else ())
+    kern = _xyz_slot_kern(payload)
+    counts = {k: telemetry.registry().counter_value(
+        "dccrg_slot_gather_programs_total", gather=k)
+        for k in ("roll3d", "slab3d", "roll_fixup", "table")}
+    if op == "run_steps":
+        g.run_steps(kern, ins, outs, 3)
+    else:
+        for _ in range(2):
+            g.update_copies_of_remote_neighbors()
+            g.apply_stencil(kern, ins, outs)
+    built = {k for k, n in counts.items() if telemetry.registry().counter_value(
+        "dccrg_slot_gather_programs_total", gather=k) > n}
+    if op == "run_steps" and n_dev > 1:
+        assert g.last_overlap["mode"] == ("full" if overlap else "off")
+    cells = g.plan.cells
+    return {n: g.get(n, cells) for n in outs}, built
+
+
+@pytest.mark.parametrize("n_dev, periodic_z, overlap, payload, op", [
+    (2, True, False, False, "run_steps"),
+    (2, False, True, False, "run_steps"),
+    (2, True, True, True, "run_steps"),
+    (4, True, True, False, "run_steps"),
+    (4, False, False, True, "run_steps"),
+    (4, False, True, True, "run_steps"),
+    (2, False, False, True, "apply_stencil"),
+    (4, True, False, False, "apply_stencil"),
+])
+def test_slab_gather_matches_one_device(monkeypatch, n_dev, periodic_z,
+                                        overlap, payload, op):
+    """A multi-device closed-form plan whose slabs are whole z planes
+    takes the slab gather (rolls and copies of whole planes, in-plane
+    rolls) and steps bitwise like the same grid on one device, with z
+    periodic or walled, overlap on or off, and a [L, W] payload field.
+    The 5 x 3 planes make L a bucket above the local rows, so pad rows
+    exist."""
+    length, periodic = (5, 3, 16), (True, False, periodic_z)
+    want, _ = _stepped(monkeypatch, 1, length, periodic, overlap, payload, op)
+    got, built = _stepped(monkeypatch, n_dev, length, periodic, overlap,
+                          payload, op)
+    assert built == {"slab3d"}
+    for n in want:
+        np.testing.assert_array_equal(got[n], want[n])
+
+
+@pytest.mark.parametrize("n_dev, length, overlap", [
+    (8, (8, 6, 4), False),  # half-plane slabs (all rows outer)
+    (3, (5, 3, 16), True),  # 16 planes over 3 devices: unequal slabs
+])
+def test_slab_gather_falls_back_off_plane_slabs(monkeypatch, n_dev, length,
+                                                overlap):
+    """Slabs that are not whole, equal z planes keep the flat roll with
+    its fixup scatter, and still step bitwise like one device."""
+    periodic = (True, False, True)
+    want, _ = _stepped(monkeypatch, 1, length, periodic, overlap, True,
+                       "run_steps")
+    got, built = _stepped(monkeypatch, n_dev, length, periodic, overlap,
+                          True, "run_steps")
+    assert built == {"roll_fixup"}
+    for n in want:
+        np.testing.assert_array_equal(got[n], want[n])
