@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import telemetry
 from ..grid import DEFAULT_NEIGHBORHOOD_ID, Grid
 from ..dense import DenseGrid
 from ..neighbors import face_masks, make_neighborhood
@@ -180,7 +181,9 @@ class PoissonSolver:
 
     def prepare(self, cells_to_solve=None, cells_to_skip=None) -> None:
         """Classify cells and compute geometry factors for the current
-        structure epoch."""
+        structure epoch. Its seconds set the plan-phase gauge
+        ``dccrg_plan_phase_seconds{phase="poisson_prepare"}``."""
+        mark = telemetry.phase_timer()
         g = self.grid
         cells = g.get_cells()
         n = len(cells)
@@ -246,6 +249,7 @@ class PoissonSolver:
             jnp.dtype(self._np_dtype)
         ) * (g.data["ctype"] == SOLVE_CELL)
         self._prepared_epoch = self._cache_key(cells_to_solve, cells_to_skip)
+        mark("poisson_prepare")
 
     # -- reductions ----------------------------------------------------
 
@@ -281,7 +285,17 @@ class PoissonSolver:
         Tables, static fields and the solve mask are ARGUMENTS of the
         compiled program (cached in the grid's shape-keyed program
         cache), so bucket-stable structure epochs reuse it instead of
-        recompiling."""
+        recompiling. Returns ``(program, bindings)``: the program is
+        called as ``program(*state, *bindings)``.
+
+        The program is named ``dccrg_poisson_solve`` (its module is
+        ``jit_dccrg_poisson_solve``) and wraps each phase in
+        ``jax.named_scope`` (metadata only): ``dccrg.matvec`` (both
+        matvecs: gather, kernel, write-back), ``dccrg.dot`` (the global
+        reductions), ``dccrg.update`` (alpha/beta, the vector updates and
+        the selects on ``go``) and, on several devices, ``dccrg.exchange``
+        (the p0/p1 halo update) and ``dccrg.repass`` (the overlap's outer
+        re-pass)."""
         g = self.grid
         fields_in_fwd = ["p0", "ilen", "ctype", "scale"] + [
             n for pair in _F_NAMES for n in pair
@@ -334,9 +348,10 @@ class PoissonSolver:
                overlap)
         prog = g._program_cache.get(key)
         if prog is not None:
-            return lambda *state: prog(*state, *bindings)
+            return prog, bindings
 
-        def run(solution, rhs, scratch, rtol, max_iterations, *rest):
+        def dccrg_poisson_solve(solution, rhs, scratch, rtol, max_iterations,
+                                *rest):
             fwd_t = rest[:nf]
             tr_t = rest[nf:nf + nt]
             ex1 = rest[nf + nt:nf + nt + n1]
@@ -348,38 +363,51 @@ class PoissonSolver:
             statics = rest[base + nrf + nrt + 1:]
 
             def fwd(*args):
-                return fwd_fn(*fwd_t, *args)
+                with jax.named_scope("dccrg.matvec"):
+                    return fwd_fn(*fwd_t, *args)
 
             def tr(*args):
-                return tr_fn(*tr_t, *args)
+                with jax.named_scope("dccrg.matvec"):
+                    return tr_fn(*tr_t, *args)
 
             def exchange1(p0):
-                return fused1(*ex1, p0)
+                with jax.named_scope("dccrg.exchange"):
+                    return fused1(*ex1, p0)
 
             def exchange2(p0, p1):
-                return fused2(*ex2, p0, p1)
+                with jax.named_scope("dccrg.exchange"):
+                    return fused2(*ex2, p0, p1)
 
             def exchange2_start(p0, p1):
-                return start2_j(*ex2[:n_sx2], p0, p1)
+                with jax.named_scope("dccrg.exchange"):
+                    return start2_j(*ex2[:n_sx2], p0, p1)
 
             def exchange2_finish(bufs, p0, p1):
-                return finish2_j(*ex2[n_sx2:], *bufs, p0, p1)
+                with jax.named_scope("dccrg.exchange"):
+                    return finish2_j(*ex2[n_sx2:], *bufs, p0, p1)
+
+            def repass(fn, tables, *args):
+                with jax.named_scope("dccrg.repass"):
+                    return fn(*tables, *args)
 
             def dot(a, b):
-                return jnp.sum(a * b * mask)
+                with jax.named_scope("dccrg.dot"):
+                    return jnp.sum(a * b * mask)
 
             # initial residual (initialize_solver, :986-1041)
             p0 = solution
             if not single:
                 (p0,) = exchange1(p0)
             (Ap0,) = fwd(p0, *statics, scratch)
-            r0 = (rhs - Ap0) * mask
+            with jax.named_scope("dccrg.update"):
+                r0 = (rhs - Ap0) * mask
             dot_r0 = dot(r0, r0)
             b2 = dot(rhs, rhs)
-            target = jnp.maximum(
-                rtol * rtol * jnp.maximum(jnp.maximum(b2, dot_r0), 1e-30),
-                1e-30,
-            )
+            with jax.named_scope("dccrg.dot"):
+                target = jnp.maximum(
+                    rtol * rtol * jnp.maximum(jnp.maximum(b2, dot_r0), 1e-30),
+                    1e-30,
+                )
 
             def cond(s):
                 return s["go"] & (s["residual"] > target) & (
@@ -396,34 +424,41 @@ class PoissonSolver:
                     (Ap0,) = fwd(p0, *statics, s["Ap0"])
                     (Atp1,) = tr(p1, *statics, s["r1"])
                     p0, p1 = exchange2_finish(bufs, p0, p1)
-                    (Ap0,) = rpf_fn(*rpf_tables, p0, *statics, Ap0)
-                    (Atp1,) = rpt_fn(*rpt_tables, p1, *statics, Atp1)
+                    (Ap0,) = repass(rpf_fn, rpf_tables, p0, *statics, Ap0)
+                    (Atp1,) = repass(rpt_fn, rpt_tables, p1, *statics, Atp1)
                 else:
                     if not single:
                         p0, p1 = exchange2(p0, p1)
                     (Ap0,) = fwd(p0, *statics, s["Ap0"])
                     (Atp1,) = tr(p1, *statics, s["r1"])
                 dot_p = dot(p1, Ap0)
-                go = (dot_p != 0) & (s["dot_r"] != 0)
-                safe_p = jnp.where(dot_p == 0, 1, dot_p)
-                alpha = jnp.where(go, s["dot_r"] / safe_p, 0.0)
-                solution = s["solution"] + alpha * p0 * mask
-                r0 = s["r0"] - alpha * Ap0 * mask
-                r1 = s["r1"] - alpha * Atp1 * mask
+                with jax.named_scope("dccrg.update"):
+                    go = (dot_p != 0) & (s["dot_r"] != 0)
+                    safe_p = jnp.where(dot_p == 0, 1, dot_p)
+                    alpha = jnp.where(go, s["dot_r"] / safe_p, 0.0)
+                    solution = s["solution"] + alpha * p0 * mask
+                    r0 = s["r0"] - alpha * Ap0 * mask
+                    r1 = s["r1"] - alpha * Atp1 * mask
                 new_dot_r = dot(r0, r1)
-                safe_r = jnp.where(s["dot_r"] == 0, 1, s["dot_r"])
-                beta = jnp.where(go, new_dot_r / safe_r, 0.0)
-                p0 = (r0 + beta * p0) * mask
-                p1 = (r1 + beta * p1) * mask
+                with jax.named_scope("dccrg.update"):
+                    safe_r = jnp.where(s["dot_r"] == 0, 1, s["dot_r"])
+                    beta = jnp.where(go, new_dot_r / safe_r, 0.0)
+                    p0 = (r0 + beta * p0) * mask
+                    p1 = (r1 + beta * p1) * mask
+                    out = {
+                        "solution": jnp.where(go, solution, s["solution"]),
+                        "r0": jnp.where(go, r0, s["r0"]),
+                        "r1": jnp.where(go, r1, s["r1"]),
+                        "p0": jnp.where(go, p0, s["p0"]),
+                        "p1": jnp.where(go, p1, s["p1"]),
+                        "Ap0": Ap0,
+                        "dot_r": jnp.where(go, new_dot_r, s["dot_r"]),
+                    }
+                residual = dot(r0, r0)
+                with jax.named_scope("dccrg.update"):
+                    out["residual"] = jnp.where(go, residual, s["residual"])
                 return {
-                    "solution": jnp.where(go, solution, s["solution"]),
-                    "r0": jnp.where(go, r0, s["r0"]),
-                    "r1": jnp.where(go, r1, s["r1"]),
-                    "p0": jnp.where(go, p0, s["p0"]),
-                    "p1": jnp.where(go, p1, s["p1"]),
-                    "Ap0": Ap0,
-                    "dot_r": jnp.where(go, new_dot_r, s["dot_r"]),
-                    "residual": jnp.where(go, dot(r0, r0), s["residual"]),
+                    **out,
                     "it": s["it"] + jnp.where(go, 1, 0),
                     "go": go,
                 }
@@ -436,9 +471,9 @@ class PoissonSolver:
             out = jax.lax.while_loop(cond, body, init)
             return out["solution"], out["it"], out["residual"]
 
-        prog = jax.jit(run)
+        prog = jax.jit(dccrg_poisson_solve)
         g._program_cache[key] = prog
-        return lambda *state: prog(*state, *bindings)
+        return prog, bindings
 
     def solve(self, rtol: float = 1e-5, max_iterations: int = 1000,
               cells_to_solve=None, cells_to_skip=None,
@@ -460,17 +495,24 @@ class PoissonSolver:
             self._remove_mean("rhs")
 
         if fused:
-            run = self._fused_solve_fn()
-            sol, it, residual = run(
-                self.grid.data["solution"], self.grid.data["rhs"],
-                self.grid.data["Ap0"],
-                jnp.asarray(rtol, dtype=self.dtype),
-                jnp.int32(max_iterations),
-            )
+            solve_span = telemetry.span("poisson.solve")
+            with solve_span:
+                prog, bindings = self._fused_solve_fn()
+                args = (self.grid.data["solution"], self.grid.data["rhs"],
+                        self.grid.data["Ap0"],
+                        jnp.asarray(rtol, dtype=self.dtype),
+                        jnp.int32(max_iterations), *bindings)
+                sol, it, residual = prog(*args)
+                iterations = int(it)
+            if solve_span is not telemetry.NULL_SPAN and telemetry.profiling():
+                # the op -> phase table of the solve program, once per
+                # program, as Grid.run_steps publishes the step program's
+                telemetry.publish_scopes(prog, args)
             self.grid.data["solution"] = sol
             if singular:
                 self._remove_mean("solution")
-            return {"iterations": int(it),
+            self._count(iterations, max_iterations)
+            return {"iterations": iterations,
                     "residual": float(np.sqrt(max(float(residual), 0.0)))}
 
         # r0 = rhs - A·solution, with boundary cells' solution as data
@@ -513,14 +555,37 @@ class PoissonSolver:
             iterations += 1
         if singular:
             self._remove_mean("solution")
+        self._count(iterations, max_iterations)
         return {"iterations": iterations, "residual": float(np.sqrt(max(residual, 0.0)))}
 
+    @staticmethod
+    def _count(iterations: int, max_iterations: int) -> None:
+        """The solve counters: BiCG iterations, and one solve labelled
+        by whether it stopped before ``max_iterations``."""
+        telemetry.inc("dccrg_poisson_iterations_total", iterations)
+        telemetry.inc("dccrg_poisson_solves_total",
+                      converged="true" if iterations < max_iterations else "false")
+
     def _remove_mean(self, field: str) -> None:
-        total = float(jnp.sum(self.grid.data[field] * self._solve_mask))
-        cnt = float(jnp.sum(self._solve_mask))
-        self.grid.data[field] = (
-            self.grid.data[field] - (total / max(cnt, 1.0)) * self._solve_mask
-        )
+        """Subtract ``field``'s mean over the solve cells, in one device
+        program (``dccrg_poisson_remove_mean``, its ops in scope
+        ``dccrg.update``), so that a traced solve runs no op outside a
+        published table."""
+        g = self.grid
+        prog = g._program_cache.get("poisson_remove_mean")
+        if prog is None:
+            def dccrg_poisson_remove_mean(x, mask):
+                with jax.named_scope("dccrg.update"):
+                    total = jnp.sum(x * mask)
+                    cnt = jnp.maximum(jnp.sum(mask), 1.0)
+                    return x - (total / cnt) * mask
+
+            prog = jax.jit(dccrg_poisson_remove_mean)
+            g._program_cache["poisson_remove_mean"] = prog
+        args = (g.data[field], self._solve_mask)
+        g.data[field] = prog(*args)
+        if telemetry.profiling():
+            telemetry.publish_scopes(prog, args)
 
 
 class DensePoissonSolver:
