@@ -295,7 +295,7 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
     the same number ``Zs`` of whole z planes and its local and ghost
     rows, read ``nx*ny`` at a time, are whole planes in id order.
 
-    Returns ``((Zs, z_offsets, W), fix)`` or None. For z offset
+    Returns ``((Zs, z_offsets, W), fix, outer)`` or None. For z offset
     ``z_offsets[k]`` the slot column starts as the local rows rolled by
     whole planes, which is right wherever the row planes ``[inner |
     outer]`` continue in grid order; ``fix[d, k, w] = (dst, src)``
@@ -305,7 +305,16 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
     so every entry is idempotent. Planes beyond a walled z end are left
     alone: the slot mask zeroes them. O(planes): inner and outer rows
     are each in id order and ghost ids are sorted, so a chunk whose
-    first and last ids bound one plane is that plane."""
+    first and last ids bound one plane is that plane.
+
+    ``outer[d, p]`` ([n_dev, Po, 1 + K] first rows) serves the
+    overlap's plane re-pass (_make_slab3d_repass): column 0 is the
+    ``p``-th outer plane of device ``d`` (rows ``[n_inner, n_local)``),
+    column ``1 + k`` the plane ``z_offsets[k]`` away from it, local or
+    ghost (its own row beyond a walled z end, where the mask zeroes
+    it). Pad planes repeat the device's first entry; a device with no
+    outer plane recomputes its first local plane, whose value does not
+    change."""
     (nx, ny, nz), per_z = cf["dims"], cf["periodic"][2]
     nxy = nx * ny
     n_loc = {len(ids) for ids in local_ids}
@@ -326,7 +335,8 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
 
     z_offsets = tuple(sorted({int(o[2]) for o in cf["offsets"]} - {0}))
     fixes = []  # [device][k] -> [(dst, src), ...]
-    for lids, gids in zip(local_ids, ghost_ids):
+    outers = []  # [device] -> [Po_d, 1 + K] first rows
+    for lids, gids, n_in in zip(local_ids, ghost_ids, n_inner):
         zl, zg = planes(lids), planes(gids)
         if zl is None or zg is None or not np.array_equal(
                 np.sort(zl), np.arange(zl.min(), zl.min() + Zs)):
@@ -348,12 +358,26 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
             per_k.append([(r * nxy, src[r]) for r in wrong]
                          or [(0, rolled[0])])
         fixes.append(per_k)
+        r_out = np.arange(int(n_in) // nxy, Zs) if int(n_in) < len(lids) \
+            else np.zeros(1, np.int64)
+        cols = [r_out * nxy]
+        for oz in z_offsets:
+            tz = zl[r_out] + oz
+            if per_z:
+                tz %= nz
+            inside = (tz >= 0) & (tz < nz)
+            cols.append(np.where(inside, first_row[np.clip(tz, 0, nz - 1)],
+                                 r_out * nxy))
+        outers.append(np.stack(cols, axis=1))
     W = max(len(f) for per_k in fixes for f in per_k) if z_offsets else 1
     fix = np.zeros((len(local_ids), max(len(z_offsets), 1), W, 2), np.int32)
     for d, per_k in enumerate(fixes):
         for k, f in enumerate(per_k):
             fix[d, k] = f + [f[0]] * (W - len(f))
-    return (Zs, z_offsets, W), fix
+    Po = max(len(o) for o in outers)
+    outer = np.stack([np.concatenate([o, np.repeat(o[:1], Po - len(o), 0)])
+                      for o in outers]).astype(np.int32)
+    return (Zs, z_offsets, W), fix, outer
 
 
 def _make_slab3d_gather(synth, L, slab, fix):
@@ -391,6 +415,45 @@ def _make_slab3d_gather(synth, L, slab, fix):
         return jnp.where(mexp, col, jnp.zeros((), col.dtype))
 
     return gather
+
+
+def _make_slab3d_repass(synth, slab, outer):
+    """The overlap's outer re-pass on a slab plan, plane by plane:
+    ``(take, gather, put)`` over the ``Po`` outer planes of ``outer``
+    ([Po, 1 + K] first rows, _plane_slabs). ``take(fl)`` slices the
+    planes' own rows; ``gather(fl, j, mask_j)`` is slot ``j``'s
+    neighbor column, the planes its z offset away in-plane rolled for
+    x/y and masked like _make_slab3d_gather; ``put(res, vals)`` writes
+    the planes back. Whole-plane slices and rolls only: no per-element
+    gather or scatter over the outer rows."""
+    (nx, ny, _nz), _per, _n0, offs_cells, *_ = synth
+    nxy = nx * ny
+    Po = outer.shape[0]
+    col_of = {oz: k for k, oz in enumerate((0,) + slab[1])}
+
+    def take(fl, k=0):
+        return jnp.concatenate([
+            jax.lax.dynamic_slice_in_dim(fl, outer[p, k], nxy)
+            for p in range(Po)])
+
+    def gather(fl, j, mask_j):
+        ox, oy, oz = offs_cells[j]
+        col = take(fl, col_of[oz])
+        trail = col.shape[1:]
+        if ox or oy:
+            col = jnp.roll(col.reshape((Po, ny, nx) + trail),
+                           shift=(-oy, -ox), axis=(1, 2))
+            col = col.reshape((Po * nxy,) + trail)
+        mexp = mask_j.reshape(mask_j.shape + (1,) * len(trail))
+        return jnp.where(mexp, col, jnp.zeros((), col.dtype))
+
+    def put(res, vals):
+        for p in range(Po):
+            res = jax.lax.dynamic_update_slice_in_dim(
+                res, vals[p * nxy:(p + 1) * nxy], outer[p, 0], 0)
+        return res
+
+    return take, gather, put
 
 
 def _make_slot_gather(kind, synth, L, use_roll, r_shifts, nrows, wr, ws,
@@ -700,9 +763,10 @@ class _HoodPlan:
         return self._roll_plan
 
     def slab_plan(self, local_ids, ghost_ids, L):
-        """``((Zs, z_offsets, W), fix)`` of the slab gather for a
-        multi-device closed-form plan whose rows are whole z planes, or
-        None (see _plane_slabs). Computed once per structure epoch."""
+        """``((Zs, z_offsets, W), fix, outer)`` of the slab gather and
+        plane re-pass for a multi-device closed-form plan whose rows are
+        whole z planes, or None (see _plane_slabs). Computed once per
+        structure epoch."""
         if self._slab is None:
             self._slab = _plane_slabs(self.closed_form, local_ids,
                                       ghost_ids, self.n_inner, L) or ()
@@ -2609,16 +2673,25 @@ class Grid:
             return env == "1"
         return self._on_accelerator()
 
+    def _outer_pays(self, hood) -> bool:
+        """Whether the overlap's outer re-pass can pay: some device has
+        outer rows [n_inner, n_local), and they are not the majority of
+        the grid (the re-pass would cost more than the hidden
+        collective)."""
+        n_out_d = (np.asarray(self.plan.n_local, dtype=np.int64)
+                   - np.asarray(hood.n_inner, dtype=np.int64))
+        return int(n_out_d.max(initial=0)) > 0 and (
+            2 * int(n_out_d.sum()) <= int(np.sum(self.plan.n_local)))
+
     def _outer_tables(self, neighborhood_id, hood, use_roll, r_shifts, roll):
-        """Host tables for the overlapped step's outer re-pass:
-        ``(outer_rows [n_dev, Wo] int32, pad R-1;
+        """Host tables for the overlapped step's element-gather outer
+        re-pass: ``(outer_rows [n_dev, Wo] int32, pad R-1;
         outer_nbr_rows [n_dev, Wo, S] int32)`` — the rows
         [n_inner, n_local) per device and their neighbor rows in the
-        full (local+ghost) array. None when overlap can't pay: no
-        outer rows, or outer is the majority of the grid (the re-pass
-        would cost more than the hidden collective). Memoized on the
-        hood (one structure epoch); capacity is sticky-bucketed so the
-        compiled program survives epochs."""
+        full (local+ghost) array. None when overlap can't pay
+        (:meth:`_outer_pays`). Memoized on the hood (one structure
+        epoch); capacity is sticky-bucketed so the compiled program
+        survives epochs."""
         if getattr(hood, "_outer_skip", False):
             return None
         cached = getattr(hood, "_outer_host", None)
@@ -2629,8 +2702,7 @@ class Grid:
         n_inner = np.asarray(hood.n_inner, dtype=np.int64)
         n_local = np.asarray(plan.n_local, dtype=np.int64)
         n_out_d = n_local - n_inner
-        if int(n_out_d.max(initial=0)) == 0 or (
-                2 * int(n_out_d.sum()) > int(n_local.sum())):
+        if not self._outer_pays(hood):
             hood._outer_skip = True
             return None
         W = self._sticky_cap(("outerW", neighborhood_id), int(n_out_d.max()))
@@ -2919,7 +2991,7 @@ class Grid:
         g_kind, slab = (self._slot_gather_kind(hood, cf, roll is not None)
                         if slotwise else (None, None))
         if slab is not None:
-            slab, fix = slab  # static (Zs, z offsets, W); device table
+            slab, fix, _outer = slab  # static (Zs, z offsets, W); tables
             tables.append(hood.dev("slab_fix", fix, sh))
         elif roll is not None:
             tables.append(hood.dev("roll_wr", roll[1], sh))
@@ -3167,7 +3239,7 @@ class Grid:
         g_kind, slab = (self._slot_gather_kind(hood, cf, use_roll)
                         if slotwise else (None, None))
         if slab is not None:
-            slab, fix = slab  # static (Zs, z offsets, W); device table
+            slab, fix, outer = slab  # static (Zs, z offsets, W); tables
             tables.append(hood.dev("slab_fix", fix, sh))
         elif use_roll:
             tables.append(hood.dev("roll_wr", roll[1], sh))
@@ -3229,23 +3301,36 @@ class Grid:
                 tables.append(hood.dev(
                     ("gsplit_nbr", use_roll) + tuple(relevant),
                     st[1], sh))
+        # the full re-pass of a slab plan recomputes whole outer planes
+        # (_make_slab3d_repass); every other re-pass gathers its rows
+        # through the [W, S] element tables
+        o_slab = False
         if overlap and o_mode is None:
-            ot = self._outer_tables(neighborhood_id, hood, use_roll,
-                                    r_shifts, roll)
-            if ot is None:
-                overlap = False
+            ot = None
+            if slab is not None:
+                o_slab = self._outer_pays(hood)
+                if o_slab:
+                    tables.append(hood.dev("slab_outer", outer, sh))
             else:
+                ot = self._outer_tables(neighborhood_id, hood, use_roll,
+                                        r_shifts, roll)
+                if ot is not None:
+                    tables.append(hood.dev("outer_rows", ot[0], sh))
+                    tables.append(hood.dev("outer_nbr_rows", ot[1], sh))
+            if o_slab or ot is not None:
                 o_mode = "full"
                 rows_split = rows_full
-                tables.append(hood.dev("outer_rows", ot[0], sh))
-                tables.append(hood.dev("outer_nbr_rows", ot[1], sh))
+            else:
+                overlap = False
         o_tabs = o_mode in ("full", "split")
+        rp_gather = ("slab3d" if o_slab else "table") if o_tabs else None
         self.last_overlap = {
             "mode": o_mode or "off",
             "rows_full": rows_full * n_out if overlap else 0,
             "rows_split": (rows_split * len(repass) if o_tabs
                            else 0) if overlap else 0,
             "repass_fields": repass if overlap else fields_out,
+            "repass_gather": rp_gather,
         }
 
         synth = _synth_key(cf)
@@ -3260,6 +3345,8 @@ class Grid:
         axis, mesh, n_dev = self.axis, self.mesh, self.n_dev
         if slotwise:
             telemetry.inc("dccrg_slot_gather_programs_total", gather=g_kind)
+        if rp_gather is not None:
+            telemetry.inc("dccrg_repass_programs_total", gather=rp_gather)
 
         def body(n_steps, nrows, noffs, nmask, *args):
             send_rs = [a[0] for a in args[: n_x * n_t]]
@@ -3288,7 +3375,11 @@ class Grid:
                 hr, hnr, hof, hm, *args = args
                 hr, hnr, hof, hm = hr[0], hnr[0], hof[0], hm[0]
                 hrc = jnp.minimum(hr, L - 1)
-            if o_tabs:
+            if o_slab:
+                o_planes, *args = args
+                rp_take, rp_gather_j, rp_put = _make_slab3d_repass(
+                    synth, slab, o_planes[0])
+            elif o_tabs:
                 orow_t, onr_t, *args = args
                 orow, onr = orow_t[0], onr_t[0]
                 orc = jnp.minimum(orow, L - 1)
@@ -3336,6 +3427,18 @@ class Grid:
                         _make_offs_col(uniform_offs, noffs,
                                        sc0 if scaled else None),
                         mask_col, n_slots, extra)
+
+                def run_outer(full, extra):
+                    # the bulk's slot loop over the outer planes alone
+                    # (closed-form slab plans: unscaled uniform offsets)
+                    og, ob = _synth_prep(synth, L,
+                                         row_gidx=rp_take(row_gidx))
+                    return _run_slotwise(
+                        kernel, {n: rp_take(full[n]) for n in fields_in},
+                        {n: full[n] for n in fields_in}, rp_gather_j,
+                        _make_offs_col(uniform_offs, noffs, None),
+                        lambda j: _synth_col(synth, og, ob, j),
+                        n_slots, extra)
             else:
                 if nmask is None:
                     nmask = _synth_mask(synth, L, row_gidx=row_gidx)
@@ -3397,7 +3500,16 @@ class Grid:
                                     fl, recv_rs[xi * n_t + t],
                                     payloads[xi * n_t + t], R)
                             state[j] = fl.at[R - 1].set(0)
-                    if o_tabs:
+                    if o_slab:
+                        with jax.named_scope("dccrg.repass"):
+                            full = dict(statics)
+                            full.update(zip(fields_out, state))
+                            o_res = run_outer(full, extra)
+                            for n in repass:
+                                result[n] = rp_put(
+                                    result[n],
+                                    o_res[n].astype(result[n].dtype))
+                    elif o_tabs:
                         with jax.named_scope("dccrg.repass"):
                             full = dict(statics)
                             full.update(zip(fields_out, state))
@@ -3467,7 +3579,8 @@ class Grid:
             + ((P(axis),) if slab else (P(axis), P(axis)) if use_roll else ())
             + ((P(axis),) if scaled else ())
             + ((P(axis),) * 4 if split else ())
-            + ((P(axis), P(axis)) if o_tabs else ())
+            + ((P(axis),) if o_slab else (P(axis), P(axis)) if o_tabs
+               else ())
             + (P(axis),) * (n_static + n_out) + (P(),) * n_extra,
             out_specs=(P(axis),) * n_out,
             check_vma=False,

@@ -145,9 +145,16 @@ def test_xla_step_program_compiles_128(topo):
 def test_xla_step_program_compiles_128_mesh4(topo):
     """The step program on a 2x2 v5e: four z slabs of whole planes take
     the slab gather, so the bulk pass holds no scatter (the flat roll's
-    fix-ups were one per slot and field)."""
+    fix-ups were one per slot and field), and the overlap's outer
+    re-pass recomputes whole planes with no gather or scatter (the
+    element re-pass gathered every outer row per slot and field)."""
     compiled = advection_step_128(topo.devices[:4])
     assert fits_hbm(compiled)
-    bulk_scatters = [ln for ln in compiled.as_text().splitlines()
+    lines = compiled.as_text().splitlines()
+    bulk_scatters = [ln for ln in lines
                      if " scatter(" in ln and "dccrg.bulk" in ln]
     assert not bulk_scatters
+    repass_elements = [ln for ln in lines if "dccrg.repass" in ln
+                       and (" gather(" in ln or " scatter(" in ln)]
+    assert not repass_elements
+    assert any("dccrg.repass" in ln for ln in lines)

@@ -214,7 +214,7 @@ def test_slotwise_include_to_raises(monkeypatch):
         g.apply_stencil(_slot_kern(), ["v", "w"], ["v"], include_to=True)
 
 
-def _mesh_grid(n_dev, length, periodic, payload):
+def _mesh_grid(n_dev, length, periodic, payload, reach=1):
     import jax
     from jax.sharding import Mesh
 
@@ -226,7 +226,7 @@ def _mesh_grid(n_dev, length, periodic, payload):
         .set_initial_length(length)
         .set_periodic(*periodic)
         .set_maximum_refinement_level(0)
-        .set_neighborhood_length(1)
+        .set_neighborhood_length(reach)
         .initialize(Mesh(np.array(jax.devices()[:n_dev]), ("dev",)),
                     partition="block")
     )
@@ -241,10 +241,13 @@ def _mesh_grid(n_dev, length, periodic, payload):
     return g
 
 
-def _xyz_slot_kern(payload):
-    """Integer-valued and bounded (mod 1021): each of the 26 slots gets
-    its own weight from its x, y AND z offset, so a neighbor taken from
-    the wrong plane, row or slot changes the result exactly."""
+def _xyz_slot_kern(payload, reach=1):
+    """Integer-valued and bounded (mod 1021): each of the
+    ``(2 reach + 1)^3 - 1`` slots gets its own weight from its x, y AND
+    z offset, so a neighbor taken from the wrong plane, row or slot
+    changes the result exactly (sums stay below 2^24: exact in f32)."""
+    b = 2 * reach + 1
+
     def init(cell, *extra):
         acc = {"v": jnp.zeros(cell["v"].shape, jnp.float32)}
         if payload:
@@ -252,8 +255,8 @@ def _xyz_slot_kern(payload):
         return acc
 
     def slot(acc, cell, nbr, offs, mask, *extra):
-        wgt = (1 + (offs[..., 0] + 1) + 3 * (offs[..., 1] + 1)
-               + 9 * (offs[..., 2] + 1)).astype(jnp.float32)
+        wgt = (1 + (offs[..., 0] + reach) + b * (offs[..., 1] + reach)
+               + b * b * (offs[..., 2] + reach)).astype(jnp.float32)
         acc = dict(acc)
         acc["v"] = acc["v"] + jnp.where(mask, wgt * nbr["v"] + nbr["w"], 0.0)
         if payload:
@@ -270,54 +273,72 @@ def _xyz_slot_kern(payload):
     return SlotwiseKernel(init, slot, finish)
 
 
-def _stepped(monkeypatch, n_dev, length, periodic, overlap, payload, op):
+def _stepped(monkeypatch, n_dev, length, periodic, overlap, payload, op,
+             reach=1):
+    """Step the grid; returns its stepped fields, the slot gathers whose
+    ``dccrg_slot_gather_programs_total`` rose, and the outer re-pass
+    gathers whose ``dccrg_repass_programs_total`` rose."""
     from dccrg_tpu import telemetry
 
     monkeypatch.setenv("DCCRG_OVERLAP", "1" if overlap else "0")
-    g = _mesh_grid(n_dev, length, periodic, payload)
+    g = _mesh_grid(n_dev, length, periodic, payload, reach)
     ins = ("v", "w") + (("p",) if payload else ())
     outs = ("v",) + (("p",) if payload else ())
-    kern = _xyz_slot_kern(payload)
-    counts = {k: telemetry.registry().counter_value(
-        "dccrg_slot_gather_programs_total", gather=k)
-        for k in ("roll3d", "slab3d", "roll_fixup", "table")}
+    kern = _xyz_slot_kern(payload, reach)
+    reg = telemetry.registry()
+    counters = (("dccrg_slot_gather_programs_total",
+                 ("roll3d", "slab3d", "roll_fixup", "table")),
+                ("dccrg_repass_programs_total", ("slab3d", "table")))
+    counts = {(c, k): reg.counter_value(c, gather=k)
+              for c, ks in counters for k in ks}
     if op == "run_steps":
         g.run_steps(kern, ins, outs, 3)
     else:
         for _ in range(2):
             g.update_copies_of_remote_neighbors()
             g.apply_stencil(kern, ins, outs)
-    built = {k for k, n in counts.items() if telemetry.registry().counter_value(
-        "dccrg_slot_gather_programs_total", gather=k) > n}
+    rose = [(c, k) for (c, k), n in counts.items()
+            if reg.counter_value(c, gather=k) > n]
+    built = {k for c, k in rose if c == counters[0][0]}
+    repassed = {k for c, k in rose if c == counters[1][0]}
     if op == "run_steps" and n_dev > 1:
         assert g.last_overlap["mode"] == ("full" if overlap else "off")
+        assert g.last_overlap["repass_gather"] == (
+            next(iter(repassed)) if overlap else None)
     cells = g.plan.cells
-    return {n: g.get(n, cells) for n in outs}, built
+    return {n: g.get(n, cells) for n in outs}, built, repassed
 
 
-@pytest.mark.parametrize("n_dev, periodic_z, overlap, payload, op", [
-    (2, True, False, False, "run_steps"),
-    (2, False, True, False, "run_steps"),
-    (2, True, True, True, "run_steps"),
-    (4, True, True, False, "run_steps"),
-    (4, False, False, True, "run_steps"),
-    (4, False, True, True, "run_steps"),
-    (2, False, False, True, "apply_stencil"),
-    (4, True, False, False, "apply_stencil"),
+@pytest.mark.parametrize("n_dev, periodic_z, overlap, payload, op, reach", [
+    (2, True, False, False, "run_steps", 1),
+    (2, False, True, False, "run_steps", 1),
+    (2, True, True, True, "run_steps", 1),
+    (4, True, True, False, "run_steps", 1),
+    (4, False, False, True, "run_steps", 1),
+    (4, False, True, True, "run_steps", 1),
+    (2, False, False, True, "apply_stencil", 1),
+    (4, True, False, False, "apply_stencil", 1),
+    (2, True, True, False, "run_steps", 2),
+    (4, True, True, True, "run_steps", 2),
+    (4, False, True, False, "run_steps", 2),
 ])
 def test_slab_gather_matches_one_device(monkeypatch, n_dev, periodic_z,
-                                        overlap, payload, op):
+                                        overlap, payload, op, reach):
     """A multi-device closed-form plan whose slabs are whole z planes
     takes the slab gather (rolls and copies of whole planes, in-plane
     rolls) and steps bitwise like the same grid on one device, with z
     periodic or walled, overlap on or off, and a [L, W] payload field.
+    With the overlap on, the outer re-pass recomputes whole planes too.
     The 5 x 3 planes make L a bucket above the local rows, so pad rows
-    exist."""
-    length, periodic = (5, 3, 16), (True, False, periodic_z)
-    want, _ = _stepped(monkeypatch, 1, length, periodic, overlap, payload, op)
-    got, built = _stepped(monkeypatch, n_dev, length, periodic, overlap,
-                          payload, op)
+    exist. Reach 2 (z offsets +-1, +-2) makes two outer planes per slab
+    side, over 32 planes so that every device keeps inner planes."""
+    length, periodic = (5, 3, 16 * reach), (True, False, periodic_z)
+    want, _, _ = _stepped(monkeypatch, 1, length, periodic, overlap, payload,
+                          op, reach)
+    got, built, repassed = _stepped(monkeypatch, n_dev, length, periodic,
+                                    overlap, payload, op, reach)
     assert built == {"slab3d"}
+    assert repassed == ({"slab3d"} if overlap else set())
     for n in want:
         np.testing.assert_array_equal(got[n], want[n])
 
@@ -329,12 +350,14 @@ def test_slab_gather_matches_one_device(monkeypatch, n_dev, periodic_z,
 def test_slab_gather_falls_back_off_plane_slabs(monkeypatch, n_dev, length,
                                                 overlap):
     """Slabs that are not whole, equal z planes keep the flat roll with
-    its fixup scatter, and still step bitwise like one device."""
+    its fixup scatter and the element-gather outer re-pass, and still
+    step bitwise like one device."""
     periodic = (True, False, True)
-    want, _ = _stepped(monkeypatch, 1, length, periodic, overlap, True,
-                       "run_steps")
-    got, built = _stepped(monkeypatch, n_dev, length, periodic, overlap,
-                          True, "run_steps")
+    want, _, _ = _stepped(monkeypatch, 1, length, periodic, overlap, True,
+                          "run_steps")
+    got, built, repassed = _stepped(monkeypatch, n_dev, length, periodic,
+                                    overlap, True, "run_steps")
     assert built == {"roll_fixup"}
+    assert repassed == ({"table"} if overlap else set())
     for n in want:
         np.testing.assert_array_equal(got[n], want[n])
