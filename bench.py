@@ -310,33 +310,6 @@ def ab_roll_vs_tables():
     return winner, roll_ups, table_ups, None
 
 
-def ab_overlap():
-    """Quick-size A/B of the overlapped fused step (DCCRG_OVERLAP)
-    against the sequential exchange->kernel order. On a single chip the
-    mesh has one device, so this only measures when >1 device is
-    visible; the record tells whether the accelerator-default overlap
-    earns its outer re-pass on real hardware. Skipped when the user
-    exported DCCRG_OVERLAP explicitly."""
-    import jax
-
-    if (os.environ.get("BENCH_SKIP_AB") == "1"
-            or "DCCRG_OVERLAP" in os.environ or len(jax.devices()) < 2):
-        return None, None
-    try:
-        os.environ["DCCRG_OVERLAP"] = "0"
-        seq, _ = bench_grid_path(AB_N, AB_STEPS, label="A/B sequential")
-        os.environ["DCCRG_OVERLAP"] = "1"
-        ovl, _ = bench_grid_path(AB_N, AB_STEPS, label="A/B overlap")
-    except Exception as e:
-        print(f"overlap A/B failed ({e!r})", file=sys.stderr)
-        return None, None
-    finally:
-        os.environ.pop("DCCRG_OVERLAP", None)
-    print(f"A/B overlap at {AB_N}^3: sequential {seq:.3g}/s vs "
-          f"overlap {ovl:.3g}/s", file=sys.stderr)
-    return seq, ovl
-
-
 def main() -> None:
     from dccrg_tpu.compat import use_compile_cache
 
@@ -347,7 +320,6 @@ def main() -> None:
     baseline = measure_baseline()
 
     user_env = {v: os.environ[v] for v in _GATHER_VARS if v in os.environ}
-    ab_seq, ab_ovl = ab_overlap()
     winner, ab_roll, ab_tables, ab_note = ab_roll_vs_tables()
     if winner is not None:
         mode_used, mode_source = winner, ("ab" if ab_note is None
@@ -417,8 +389,6 @@ def main() -> None:
                 "gather_mode_source": mode_source,
                 "ab_roll_updates_per_sec": ab_roll,
                 "ab_tables_updates_per_sec": ab_tables,
-                "ab_sequential_updates_per_sec": ab_seq,
-                "ab_overlap_updates_per_sec": ab_ovl,
                 "bf16_updates_per_sec": bf16_ups,
                 "bf16_l2_error": bf16_l2,
                 "grid_path_pallas_updates_per_sec": bulk_ups,

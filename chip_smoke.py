@@ -126,13 +126,12 @@ def grid_run(mesh, label):
                        if k[0] in ("steploop", "bulksteploop")})
     gather = ("roll, closed-form plan" if hood.closed_form is not None
               else "roll" if grid._use_roll_gather() else "tables")
-    overlap = getattr(grid, "last_overlap", {}).get("mode", "n/a")
     print(f"[{label}] devices {grid.n_dev}: init {t_init:.3f} s (constructor "
           f"{t_build:.3f} s, then first sync), compile "
           f"{t_compile:.3f} s, {STEPS} steps {t_steady:.3f} s "
           f"(smoke timing, not a benchmark)")
-    print(f"[{label}] program {programs}, gather {gather}, overlap "
-          f"{overlap}, native host library "
+    print(f"[{label}] program {programs}, gather {gather}, native host "
+          f"library "
           f"{'loaded' if native.lib is not None else 'not loaded'}")
     if not math.isfinite(mass):
         raise AssertionError(f"[{label}] density sum is {mass}")

@@ -62,8 +62,7 @@ from .length import GridLength
 from .topology import GridTopology
 from .mapping import Mapping
 from .geometry import NoGeometry, CartesianGeometry, StretchedCartesianGeometry
-from .grid import (DEFAULT_NEIGHBORHOOD_ID, Grid, SlotwiseKernel,
-                   default_mesh, ghost_split_enabled)
+from .grid import DEFAULT_NEIGHBORHOOD_ID, Grid, SlotwiseKernel, default_mesh
 from .dense import DenseGrid, dense_mesh
 from .verify import VerificationError, verify_all
 from .txn import (GridInvariantError, MutationAbortedError, MutationError,
@@ -107,7 +106,6 @@ __all__ = [
     "DenseGrid",
     "DEFAULT_NEIGHBORHOOD_ID",
     "default_mesh",
-    "ghost_split_enabled",
     "dense_mesh",
     "VerificationError",
     "verify_all",

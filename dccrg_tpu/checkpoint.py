@@ -464,8 +464,8 @@ def state_digest(grid, fields=None) -> str:
     addressable shards (compare per rank, or gather host-side).
 
     Gather-mode independent BY CONSTRUCTION: the digest reads only the
-    owned payload rows, which every gather mode (roll, tables,
-    overlap) leaves in the same layout — pinned by the SDC suite
+    owned payload rows, which every gather mode (roll, tables) leaves
+    in the same layout — pinned by the SDC suite
     (tests/test_integrity.py), because the shadow-audit comparator
     assumes a mode-dependent digest can never raise a false alarm."""
     import hashlib

@@ -295,7 +295,7 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
     the same number ``Zs`` of whole z planes and its local and ghost
     rows, read ``nx*ny`` at a time, are whole planes in id order.
 
-    Returns ``((Zs, z_offsets, W), fix, outer)`` or None. For z offset
+    Returns ``((Zs, z_offsets, W), fix)`` or None. For z offset
     ``z_offsets[k]`` the slot column starts as the local rows rolled by
     whole planes, which is right wherever the row planes ``[inner |
     outer]`` continue in grid order; ``fix[d, k, w] = (dst, src)``
@@ -305,16 +305,7 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
     so every entry is idempotent. Planes beyond a walled z end are left
     alone: the slot mask zeroes them. O(planes): inner and outer rows
     are each in id order and ghost ids are sorted, so a chunk whose
-    first and last ids bound one plane is that plane.
-
-    ``outer[d, p]`` ([n_dev, Po, 1 + K] first rows) serves the
-    overlap's plane re-pass (_make_slab3d_repass): column 0 is the
-    ``p``-th outer plane of device ``d`` (rows ``[n_inner, n_local)``),
-    column ``1 + k`` the plane ``z_offsets[k]`` away from it, local or
-    ghost (its own row beyond a walled z end, where the mask zeroes
-    it). Pad planes repeat the device's first entry; a device with no
-    outer plane recomputes its first local plane, whose value does not
-    change."""
+    first and last ids bound one plane is that plane."""
     (nx, ny, nz), per_z = cf["dims"], cf["periodic"][2]
     nxy = nx * ny
     n_loc = {len(ids) for ids in local_ids}
@@ -335,8 +326,7 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
 
     z_offsets = tuple(sorted({int(o[2]) for o in cf["offsets"]} - {0}))
     fixes = []  # [device][k] -> [(dst, src), ...]
-    outers = []  # [device] -> [Po_d, 1 + K] first rows
-    for lids, gids, n_in in zip(local_ids, ghost_ids, n_inner):
+    for lids, gids in zip(local_ids, ghost_ids):
         zl, zg = planes(lids), planes(gids)
         if zl is None or zg is None or not np.array_equal(
                 np.sort(zl), np.arange(zl.min(), zl.min() + Zs)):
@@ -358,26 +348,12 @@ def _plane_slabs(cf, local_ids, ghost_ids, n_inner, L):
             per_k.append([(r * nxy, src[r]) for r in wrong]
                          or [(0, rolled[0])])
         fixes.append(per_k)
-        r_out = np.arange(int(n_in) // nxy, Zs) if int(n_in) < len(lids) \
-            else np.zeros(1, np.int64)
-        cols = [r_out * nxy]
-        for oz in z_offsets:
-            tz = zl[r_out] + oz
-            if per_z:
-                tz %= nz
-            inside = (tz >= 0) & (tz < nz)
-            cols.append(np.where(inside, first_row[np.clip(tz, 0, nz - 1)],
-                                 r_out * nxy))
-        outers.append(np.stack(cols, axis=1))
     W = max(len(f) for per_k in fixes for f in per_k) if z_offsets else 1
     fix = np.zeros((len(local_ids), max(len(z_offsets), 1), W, 2), np.int32)
     for d, per_k in enumerate(fixes):
         for k, f in enumerate(per_k):
             fix[d, k] = f + [f[0]] * (W - len(f))
-    Po = max(len(o) for o in outers)
-    outer = np.stack([np.concatenate([o, np.repeat(o[:1], Po - len(o), 0)])
-                      for o in outers]).astype(np.int32)
-    return (Zs, z_offsets, W), fix, outer
+    return (Zs, z_offsets, W), fix
 
 
 def _make_slab3d_gather(synth, L, slab, fix):
@@ -415,45 +391,6 @@ def _make_slab3d_gather(synth, L, slab, fix):
         return jnp.where(mexp, col, jnp.zeros((), col.dtype))
 
     return gather
-
-
-def _make_slab3d_repass(synth, slab, outer):
-    """The overlap's outer re-pass on a slab plan, plane by plane:
-    ``(take, gather, put)`` over the ``Po`` outer planes of ``outer``
-    ([Po, 1 + K] first rows, _plane_slabs). ``take(fl)`` slices the
-    planes' own rows; ``gather(fl, j, mask_j)`` is slot ``j``'s
-    neighbor column, the planes its z offset away in-plane rolled for
-    x/y and masked like _make_slab3d_gather; ``put(res, vals)`` writes
-    the planes back. Whole-plane slices and rolls only: no per-element
-    gather or scatter over the outer rows."""
-    (nx, ny, _nz), _per, _n0, offs_cells, *_ = synth
-    nxy = nx * ny
-    Po = outer.shape[0]
-    col_of = {oz: k for k, oz in enumerate((0,) + slab[1])}
-
-    def take(fl, k=0):
-        return jnp.concatenate([
-            jax.lax.dynamic_slice_in_dim(fl, outer[p, k], nxy)
-            for p in range(Po)])
-
-    def gather(fl, j, mask_j):
-        ox, oy, oz = offs_cells[j]
-        col = take(fl, col_of[oz])
-        trail = col.shape[1:]
-        if ox or oy:
-            col = jnp.roll(col.reshape((Po, ny, nx) + trail),
-                           shift=(-oy, -ox), axis=(1, 2))
-            col = col.reshape((Po * nxy,) + trail)
-        mexp = mask_j.reshape(mask_j.shape + (1,) * len(trail))
-        return jnp.where(mexp, col, jnp.zeros((), col.dtype))
-
-    def put(res, vals):
-        for p in range(Po):
-            res = jax.lax.dynamic_update_slice_in_dim(
-                res, vals[p * nxy:(p + 1) * nxy], outer[p, 0], 0)
-        return res
-
-    return take, gather, put
 
 
 def _make_slot_gather(kind, synth, L, use_roll, r_shifts, nrows, wr, ws,
@@ -525,25 +462,15 @@ class SlotwiseKernel:
     - ``finish(carry, cell_fields, *extra) -> {name: [L, ...]}``
 
     Instances are also plain dense kernels (``__call__`` loops the
-    slots over axis 1), so the surface-sized passes — hard rows near
-    refinement, the overlap outer re-pass — and the CPU path use the
-    same object unchanged. The slots accumulate sequentially, so
-    results match the dense contract's axis-1 reduction only to
-    float re-association.
+    slots over axis 1), so the surface-sized pass over hard rows near
+    refinement and the CPU path use the same object unchanged. The
+    slots accumulate sequentially, so results match the dense
+    contract's axis-1 reduction only to float re-association."""
 
-    ``ghost_deps`` optionally declares per-output ghost dependencies
-    (``{out_field: (in_fields whose NEIGHBOR values the computation
-    of out_field reads)}``) — the per-field ghost-split contract (see
-    :func:`ghost_split_enabled`). A missing output defaults to "all
-    of fields_in" (the conservative full re-pass)."""
-
-    def __init__(self, init, slot, finish, ghost_deps=None):
+    def __init__(self, init, slot, finish):
         self.init = init
         self.slot = slot
         self.finish = finish
-        if ghost_deps is not None:
-            self.ghost_deps = {k: tuple(v)
-                               for k, v in dict(ghost_deps).items()}
 
     def __call__(self, cell_fields, nbr_fields, offs, mask, *extra):
         return _run_slotwise(
@@ -552,21 +479,6 @@ class SlotwiseKernel:
             (lambda j: offs[:, j]) if offs.ndim == 3 else
             (lambda j: offs[j]),
             lambda j: mask[..., j], mask.shape[-1], extra)
-
-
-def ghost_split_enabled(default: bool = True) -> bool:
-    """The ``DCCRG_GHOST_SPLIT`` env knob: per-field ghost-split for
-    the overlapped step's outer re-pass (default on). A kernel that
-    declares ``ghost_deps`` then re-runs only the outer rows feeding
-    the fields that actually exchanged, and scatters only the output
-    fields whose declared ghost reads intersect the exchanged set.
-    ``0`` compiles the pre-split program bit-identically (the
-    negative pin — same discipline as ``DCCRG_INTEGRITY=0``); kernels
-    without a declaration are never split either way."""
-    v = os.environ.get("DCCRG_GHOST_SPLIT", "")
-    if v == "":
-        return default
-    return v not in ("0", "off", "false", "no")
 
 
 def default_mesh(devices=None) -> Mesh:
@@ -763,10 +675,9 @@ class _HoodPlan:
         return self._roll_plan
 
     def slab_plan(self, local_ids, ghost_ids, L):
-        """``((Zs, z_offsets, W), fix, outer)`` of the slab gather and
-        plane re-pass for a multi-device closed-form plan whose rows are
-        whole z planes, or None (see _plane_slabs). Computed once per
-        structure epoch."""
+        """``((Zs, z_offsets, W), fix)`` of the slab gather for a
+        multi-device closed-form plan whose rows are whole z planes, or
+        None (see _plane_slabs). Computed once per structure epoch."""
         if self._slab is None:
             self._slab = _plane_slabs(self.closed_form, local_ids,
                                       ghost_ids, self.n_inner, L) or ()
@@ -2656,278 +2567,6 @@ class Grid:
                 return "slab3d", slab
         return ("roll_fixup" if use_roll else "table"), None
 
-    def _use_overlap(self) -> bool:
-        """Overlapped fused steps: start the halo collectives, run the
-        bulk kernel on pre-exchange state (inner rows' results are
-        final — they read no ghosts), then redo just the outer rows
-        after the scatter. Removes the collective -> kernel dependency
-        so XLA's async collective-permute runs under the MXU work —
-        the reference's solve-inner-while-messages-fly
-        (dccrg.hpp:5046-5413, tests/advection/2d.cpp:327-343). Costs a
-        surface-sized second kernel pass, so default on for
-        accelerators only — the CPU backend has no async
-        collective-permute to hide and the measured CPU A/B is 0.89x
-        (PERF.md); override with DCCRG_OVERLAP=0/1."""
-        env = os.environ.get("DCCRG_OVERLAP")
-        if env in ("0", "1"):
-            return env == "1"
-        return self._on_accelerator()
-
-    def _outer_pays(self, hood) -> bool:
-        """Whether the overlap's outer re-pass can pay: some device has
-        outer rows [n_inner, n_local), and they are not the majority of
-        the grid (the re-pass would cost more than the hidden
-        collective)."""
-        n_out_d = (np.asarray(self.plan.n_local, dtype=np.int64)
-                   - np.asarray(hood.n_inner, dtype=np.int64))
-        return int(n_out_d.max(initial=0)) > 0 and (
-            2 * int(n_out_d.sum()) <= int(np.sum(self.plan.n_local)))
-
-    def _outer_tables(self, neighborhood_id, hood, use_roll, r_shifts, roll):
-        """Host tables for the overlapped step's element-gather outer
-        re-pass: ``(outer_rows [n_dev, Wo] int32, pad R-1;
-        outer_nbr_rows [n_dev, Wo, S] int32)`` — the rows
-        [n_inner, n_local) per device and their neighbor rows in the
-        full (local+ghost) array. None when overlap can't pay
-        (:meth:`_outer_pays`). Memoized on the hood (one structure
-        epoch); capacity is sticky-bucketed so the compiled program
-        survives epochs."""
-        if getattr(hood, "_outer_skip", False):
-            return None
-        cached = getattr(hood, "_outer_host", None)
-        if cached is not None:
-            return cached
-        plan = self.plan
-        L, R = plan.L, plan.R
-        n_inner = np.asarray(hood.n_inner, dtype=np.int64)
-        n_local = np.asarray(plan.n_local, dtype=np.int64)
-        n_out_d = n_local - n_inner
-        if not self._outer_pays(hood):
-            hood._outer_skip = True
-            return None
-        W = self._sticky_cap(("outerW", neighborhood_id), int(n_out_d.max()))
-        orow = np.full((self.n_dev, W), R - 1, dtype=np.int32)
-        for d in range(self.n_dev):
-            k = int(n_out_d[d])
-            orow[d, :k] = np.arange(n_inner[d], n_local[d], dtype=np.int32)
-        if use_roll:
-            # neighbor row = row + shift_j, overridden by the roll
-            # plan's fixups (ghost reads are always fixups); masked
-            # slots may hold junk — the outer gather re-applies the
-            # mask exactly as _make_nbr_gather does
-            shifts = np.asarray(r_shifts, dtype=np.int64)
-            S = len(shifts)
-            onr64 = orow.astype(np.int64)[:, :, None] + shifts[None, None, :]
-            wr = np.asarray(roll[1])
-            ws = np.asarray(roll[2])
-            for d in range(self.n_dev):
-                lo, hi = int(n_inner[d]), int(n_local[d])
-                for j in range(S):
-                    wrow = wr[d, j]
-                    sel = (wrow >= lo) & (wrow < hi)
-                    onr64[d, wrow[sel] - lo, j] = ws[d, j][sel]
-            onr = np.clip(onr64, 0, R - 1).astype(np.int32)
-            for d in range(self.n_dev):
-                onr[d, int(n_out_d[d]):] = R - 1
-        else:
-            nbr = np.asarray(hood.nbr_rows)
-            S = nbr.shape[2]
-            onr = np.full((self.n_dev, W, S), R - 1, dtype=np.int32)
-            for d in range(self.n_dev):
-                k = int(n_out_d[d])
-                onr[d, :k] = nbr[d, orow[d, :k]]
-        hood._outer_host = (orow, onr)
-        return hood._outer_host
-
-    def _refreshed_ghost_mask(self, neighborhood_id, names):
-        """``[n_dev, R]`` bool: ghost rows that RECEIVE fresh bytes
-        when ``names`` exchange — per-field post-transfer-predicate
-        receive rows. The zero pad row is excluded (the exchange
-        rewrites it to the 0 it already holds)."""
-        R = self.plan.R
-        m = np.zeros((self.n_dev, R), dtype=bool)
-        for n in names:
-            c = self._field_pair_compact(neighborhood_id, n)
-            m[c["q"], c["rrow"]] = True
-        m[:, R - 1] = False
-        return m
-
-    def _split_outer_tables(self, neighborhood_id, hood, use_roll,
-                            r_shifts, roll, relevant):
-        """Ghost-split outer tables: like :meth:`_outer_tables` but
-        restricted to the local rows whose gather actually READS a
-        ghost row refreshed by exchanging ``relevant`` — the rows a
-        step exchanging only those fields can invalidate. Rows that
-        are outer only through the to-lists, rows whose ghost
-        neighbors are all transfer-predicate-filtered, and (on AMR
-        hybrid plans) rows whose ghost reads ride the hard tables'
-        own unconditional re-pass never qualify. Returns ``(orow
-        [n_dev, W], onr [n_dev, W, S], rows_total)`` or None when no
-        row qualifies; memoized per ``relevant`` on the hood."""
-        cache = getattr(hood, "_split_outer", None)
-        if cache is None:
-            cache = hood._split_outer = {}
-        # the gather mode is part of the key: roll callers (the step
-        # loop on accelerators) and table callers (_make_outer_repass)
-        # build format-incompatible onr tables for the same rows
-        key = (bool(use_roll), tuple(relevant))
-        if key in cache:
-            return cache[key]
-        plan = self.plan
-        L, R = plan.L, plan.R
-        n_local = np.asarray(plan.n_local, dtype=np.int64)
-        refreshed = self._refreshed_ghost_mask(neighborhood_id, relevant)
-        row_sets = []
-        if use_roll:
-            # ghost reads are always roll-plan fixups (the shifts only
-            # reach local rows), so membership falls out of the fixup
-            # tables alone; pad fixup entries are (0, 0) — row 0 is
-            # local, never a refreshed ghost, so pads never select
-            wr = np.asarray(roll[1])
-            ws = np.asarray(roll[2])
-            for d in range(self.n_dev):
-                sel = refreshed[d][ws[d]]
-                rows = np.unique(wr[d][sel]).astype(np.int64)
-                row_sets.append(rows[rows < n_local[d]])
-        else:
-            nbr = np.asarray(hood.nbr_rows)
-            msk = np.asarray(hood.nbr_mask)
-            for d in range(self.n_dev):
-                k = int(n_local[d])
-                hit = (msk[d, :k] & refreshed[d][nbr[d, :k]]).any(axis=1)
-                row_sets.append(np.nonzero(hit)[0].astype(np.int64))
-        rows_total = int(sum(len(r) for r in row_sets))
-        if rows_total == 0:
-            cache[key] = None
-            return None
-        W = self._sticky_cap(("gsplitW", neighborhood_id, key),
-                             int(max(len(r) for r in row_sets)))
-        orow = np.full((self.n_dev, W), R - 1, dtype=np.int32)
-        for d, rows in enumerate(row_sets):
-            orow[d, :len(rows)] = rows
-        if use_roll:
-            shifts = np.asarray(r_shifts, dtype=np.int64)
-            S = len(shifts)
-            onr64 = orow.astype(np.int64)[:, :, None] + shifts[None, None, :]
-            wr = np.asarray(roll[1])
-            ws = np.asarray(roll[2])
-            for d, rows in enumerate(row_sets):
-                if not len(rows):
-                    continue
-                for j in range(S):
-                    wrow = wr[d, j]
-                    pos = np.searchsorted(rows, wrow)
-                    sel = (pos < len(rows)) & (
-                        rows[np.minimum(pos, len(rows) - 1)] == wrow)
-                    onr64[d, pos[sel], j] = ws[d, j][sel]
-            onr = np.clip(onr64, 0, R - 1).astype(np.int32)
-            for d, rows in enumerate(row_sets):
-                onr[d, len(rows):] = R - 1
-        else:
-            nbr = np.asarray(hood.nbr_rows)
-            S = nbr.shape[2]
-            onr = np.full((self.n_dev, W, S), R - 1, dtype=np.int32)
-            for d, rows in enumerate(row_sets):
-                onr[d, :len(rows)] = nbr[d, rows]
-        cache[key] = (orow, onr, rows_total)
-        return cache[key]
-
-    def _make_outer_repass(self, kernel, fields_in, fields_out,
-                           neighborhood_id, exchange_names):
-        """A compiled fix-the-refreshed-rows pass for split-overlap
-        treatments of stencils OUTSIDE the fused step loop (the
-        Poisson fused-CG matvec): recomputes ``kernel`` at exactly the
-        local rows whose gather reads a ghost row refreshed by
-        exchanging ``exchange_names``, scattering the results into
-        already-computed bulk outputs. The caller runs the bulk
-        stencil on PRE-exchange state (rows not returned here read no
-        refreshed ghosts, so their bulk results are final), lands the
-        halos, then calls this pass.
-
-        Returns ``(fn, tables)`` with ``out = fn(*tables,
-        *fields_in_arrays, *bulk_out_arrays)`` (full ``[n_dev, R,
-        ...]`` arrays in and out), or None when the plan is
-        unsupported (AMR hybrid hard tables — those rows ride their
-        own unconditional re-pass) or no row qualifies."""
-        hood = self.plan.hoods[neighborhood_id]
-        if hood.hard_nbr_rows is not None:
-            return None
-        try:
-            msk = np.asarray(hood.nbr_mask)
-        except Exception:  # noqa: BLE001 - table-free plan shapes
-            return None
-        if msk is None or getattr(msk, "ndim", 0) != 3:
-            return None
-        exch = tuple(sorted(exchange_names))
-        st = self._split_outer_tables(neighborhood_id, hood, False,
-                                      None, None, exch)
-        if st is None:
-            return None
-        orow_h, onr_h, _rows = st
-        L, R = self.plan.L, self.plan.R
-        n_dev, W = orow_h.shape
-        S = onr_h.shape[2]
-        n_local = np.asarray(self.plan.n_local, dtype=np.int64)
-        omask_h = np.zeros((n_dev, W, S), dtype=bool)
-        kper = []
-        for d in range(n_dev):
-            rows = orow_h[d][orow_h[d] < n_local[d]]
-            kper.append(rows)
-            omask_h[d, :len(rows)] = msk[d, rows]
-        if hood.offs_const is not None:
-            off = np.asarray(hood.offs_const)
-            ooffs_h = (omask_h[..., None]
-                       * off[None, None, :, :]).astype(np.int32)
-            if hood.scale_rows is not None:
-                sc = np.asarray(hood.scale_rows)
-                scw = np.ones((n_dev, W), dtype=sc.dtype)
-                for d, rows in enumerate(kper):
-                    scw[d, :len(rows)] = sc[d, rows]
-                ooffs_h = ooffs_h * scw[:, :, None, None]
-        else:
-            offs_all = np.asarray(hood.nbr_offs)
-            ooffs_h = np.zeros((n_dev, W, S, 3), dtype=offs_all.dtype)
-            for d, rows in enumerate(kper):
-                ooffs_h[d, :len(rows)] = offs_all[d, rows]
-        sh = self._sharding()
-        tables = [hood.dev(("orp", exch, "rows"), orow_h, sh),
-                  hood.dev(("orp", exch, "nbr"), onr_h, sh),
-                  hood.dev(("orp", exch, "mask"), omask_h, sh),
-                  hood.dev(("orp", exch, "offs"), ooffs_h, sh)]
-        fields_in = tuple(fields_in)
-        fields_out = tuple(fields_out)
-        key = ("outer_repass", kernel, fields_in, fields_out,
-               neighborhood_id, exch, L, R)
-        fn = self._program_cache.get(key)
-        if fn is not None:
-            return fn, tables
-        axis, mesh = self.axis, self.mesh
-        nin, nout = len(fields_in), len(fields_out)
-
-        def body(orow, onr, omask, ooffs, *args):
-            orow, onr = orow[0], onr[0]
-            omask, ooffs = omask[0], ooffs[0]
-            orc = jnp.minimum(orow, L - 1)
-            fins = {n: a[0] for n, a in zip(fields_in, args[:nin])}
-            bulk = [a[0] for a in args[nin:nin + nout]]
-            cell = {n: fins[n][:L][orc] for n in fields_in}
-            nbr = {n: fins[n][onr] for n in fields_in}
-            res = kernel(cell, nbr, ooffs, omask)
-            outs = []
-            for n, b in zip(fields_out, bulk):
-                fixed = b[:L].at[orow].set(res[n].astype(b.dtype),
-                                           mode="drop")
-                outs.append(b.at[:L].set(fixed)[None])
-            return tuple(outs)
-
-        mapped = _shard_map(
-            body, mesh=mesh,
-            in_specs=(P(axis),) * (4 + nin + nout),
-            out_specs=(P(axis),) * nout, check_vma=False)
-        fn = jax.jit(lambda *a: mapped(*a))
-        self._program_cache[key] = fn
-        return fn, tables
-
     def _make_stencil(self, kernel, fields_in, fields_out, neighborhood_id, include_to,
                       n_extra=0):
         """(program, bound tables) for a gather stencil. The jitted
@@ -2991,7 +2630,7 @@ class Grid:
         g_kind, slab = (self._slot_gather_kind(hood, cf, roll is not None)
                         if slotwise else (None, None))
         if slab is not None:
-            slab, fix, _outer = slab  # static (Zs, z offsets, W); tables
+            slab, fix = slab  # static (Zs, z offsets, W); table
             tables.append(hood.dev("slab_fix", fix, sh))
         elif roll is not None:
             tables.append(hood.dev("roll_wr", roll[1], sh))
@@ -3145,16 +2784,16 @@ class Grid:
     ):
         """One jitted program running ``n_steps`` time steps on device.
 
-        Each iteration refreshes ghost rows of ``exchange_fields``
-        (an all_to_all, as update_copies_of_remote_neighbors), gathers
-        neighbors and runs ``kernel`` (same signature as apply_stencil's),
-        and writes the result into ``fields_out`` — the whole time loop
-        is a single XLA program (lax.fori_loop), so exchange, stencil
-        and apply fuse with no host round-trips. This is the TPU answer
-        to the reference's start/solve-inner/wait/solve-outer overlap
-        (dccrg.hpp:5046-5413, tests/advection/2d.cpp:327-343): XLA
-        overlaps the collective with independent compute inside one
-        program instead of split-phase host calls.
+        Each iteration refreshes ghost rows of ``exchange_fields`` (as
+        update_copies_of_remote_neighbors; several devices only), then
+        gathers neighbors and runs ``kernel`` (same signature as
+        apply_stencil's) over every local row, then writes the result
+        into ``fields_out``: scopes ``dccrg.exchange``, ``dccrg.bulk``
+        and ``dccrg.apply``. The whole time loop is a single XLA
+        program (lax.fori_loop) with no host round-trips. The
+        reference's start/solve-inner/wait/solve-outer overlap
+        (dccrg.hpp:5046-5413, tests/advection/2d.cpp:327-343) is not
+        restated inside it: the bulk pass reads the landed ghosts.
 
         ``exchange_fields`` must be a subset of ``fields_out`` (fields
         that change per step); static fields' ghosts are assumed valid
@@ -3239,7 +2878,7 @@ class Grid:
         g_kind, slab = (self._slot_gather_kind(hood, cf, use_roll)
                         if slotwise else (None, None))
         if slab is not None:
-            slab, fix, outer = slab  # static (Zs, z offsets, W); tables
+            slab, fix = slab  # static (Zs, z offsets, W); table
             tables.append(hood.dev("slab_fix", fix, sh))
         elif use_roll:
             tables.append(hood.dev("roll_wr", roll[1], sh))
@@ -3252,92 +2891,10 @@ class Grid:
             tables.append(hood.dev("hard_nbr_rows", hood.hard_nbr_rows, sh))
             tables.append(hood.dev("hard_offs", hood.hard_offs, sh))
             tables.append(hood.dev("hard_mask", hood.hard_mask, sh))
-        overlap = (self.n_dev > 1 and hood.n_inner is not None
-                   and n_x > 0 and self._use_overlap())
-        # per-field ghost split (DCCRG_GHOST_SPLIT, default on): a
-        # kernel declaring ghost_deps re-runs only the outer rows
-        # feeding the fields that actually exchanged, and scatters
-        # only the outputs whose declared ghost reads intersect the
-        # exchanged set. Without a declaration (or with the knob off)
-        # the pre-split program compiles bit-identically below.
-        deps = getattr(kernel, "ghost_deps", None)
-        o_mode = None          # "full" | "split" | "none" once engaged
-        repass = fields_out    # outputs the outer re-pass scatters
-        rows_full = rows_split = 0
-        if overlap:
-            rows_full = int((np.asarray(self.plan.n_local)
-                             - np.asarray(hood.n_inner)).sum())
-        if overlap and deps is not None and ghost_split_enabled():
-            xn = tuple(fields_out[j] for j in exch_idx)
-            repass = tuple(F for F in fields_out
-                           if set(deps.get(F, fields_in)) & set(xn))
-            relevant = tuple(sorted(set().union(set(), *(
-                set(deps.get(F, fields_in)) & set(xn)
-                for F in repass))))
-            st = (self._split_outer_tables(
-                neighborhood_id, hood, use_roll, r_shifts, roll,
-                relevant) if repass else None)
-            if st is None:
-                # nothing needs a re-pass: overlap with the re-pass
-                # elided entirely (the exchanged ghosts feed no output
-                # this kernel computes, or no local row reads them)
-                o_mode, repass = "none", ()
-            elif repass == fields_out and st[2] >= rows_full:
-                # the split saves nothing over the full re-pass: fall
-                # through to the pre-split program (same key, same
-                # tables — the shared compile IS the negative pin)
-                o_mode, repass = None, fields_out
-            elif 2 * st[2] > int(np.asarray(self.plan.n_local).sum()):
-                overlap = False  # the re-pass outweighs the hidden
-                repass = fields_out  # collective even split
-            else:
-                o_mode, rows_split = "split", st[2]
-                # use_roll in the upload keys: the OOM fallback chain
-                # (guarded_step) can compile roll AND table programs
-                # over one hood, and their onr formats differ
-                tables.append(hood.dev(
-                    ("gsplit_rows", use_roll) + tuple(relevant),
-                    st[0], sh))
-                tables.append(hood.dev(
-                    ("gsplit_nbr", use_roll) + tuple(relevant),
-                    st[1], sh))
-        # the full re-pass of a slab plan recomputes whole outer planes
-        # (_make_slab3d_repass); every other re-pass gathers its rows
-        # through the [W, S] element tables
-        o_slab = False
-        if overlap and o_mode is None:
-            ot = None
-            if slab is not None:
-                o_slab = self._outer_pays(hood)
-                if o_slab:
-                    tables.append(hood.dev("slab_outer", outer, sh))
-            else:
-                ot = self._outer_tables(neighborhood_id, hood, use_roll,
-                                        r_shifts, roll)
-                if ot is not None:
-                    tables.append(hood.dev("outer_rows", ot[0], sh))
-                    tables.append(hood.dev("outer_nbr_rows", ot[1], sh))
-            if o_slab or ot is not None:
-                o_mode = "full"
-                rows_split = rows_full
-            else:
-                overlap = False
-        o_tabs = o_mode in ("full", "split")
-        rp_gather = ("slab3d" if o_slab else "table") if o_tabs else None
-        self.last_overlap = {
-            "mode": o_mode or "off",
-            "rows_full": rows_full * n_out if overlap else 0,
-            "rows_split": (rows_split * len(repass) if o_tabs
-                           else 0) if overlap else 0,
-            "repass_fields": repass if overlap else fields_out,
-            "repass_gather": rp_gather,
-        }
-
         synth = _synth_key(cf)
         key = ("steploop", kernel, fields_in, fields_out, exch_idx, n_extra,
                L, R, uniform_offs, scaled, split, r_shifts, synth, deltas,
-               overlap, slab) + ((("gsplit", o_mode, repass),)
-                                 if o_mode in ("split", "none") else ())
+               slab)
         fn = self._program_cache.get(key)
         if fn is not None:
             return fn, tables, static_in
@@ -3345,8 +2902,6 @@ class Grid:
         axis, mesh, n_dev = self.axis, self.mesh, self.n_dev
         if slotwise:
             telemetry.inc("dccrg_slot_gather_programs_total", gather=g_kind)
-        if rp_gather is not None:
-            telemetry.inc("dccrg_repass_programs_total", gather=rp_gather)
 
         def body(n_steps, nrows, noffs, nmask, *args):
             send_rs = [a[0] for a in args[: n_x * n_t]]
@@ -3375,14 +2930,6 @@ class Grid:
                 hr, hnr, hof, hm, *args = args
                 hr, hnr, hof, hm = hr[0], hnr[0], hof[0], hm[0]
                 hrc = jnp.minimum(hr, L - 1)
-            if o_slab:
-                o_planes, *args = args
-                rp_take, rp_gather_j, rp_put = _make_slab3d_repass(
-                    synth, slab, o_planes[0])
-            elif o_tabs:
-                orow_t, onr_t, *args = args
-                orow, onr = orow_t[0], onr_t[0]
-                orc = jnp.minimum(orow, L - 1)
             def exchange_one(fl, xi):
                 # per-peer-offset ppermutes of compact buffers, or the
                 # dense all_to_all fallback (see _exchange_programs)
@@ -3397,48 +2944,11 @@ class Grid:
                 if synth is not None:
                     sgidx, sbase = _synth_prep(synth, L, row_gidx=row_gidx)
                     mask_col = lambda j: _synth_col(synth, sgidx, sbase, j)
-
-                    def mask_rows(rows):
-                        g, b = sgidx[rows], sbase[rows]
-                        return jnp.stack(
-                            [_synth_col(synth, g, b, j)
-                             for j in range(n_slots)], axis=1)
                 else:
                     mask_col = lambda j: nmask[:, j]
-                    mask_rows = lambda rows: nmask[rows]
                 slot_gather = _make_slot_gather(
                     g_kind, synth, L, use_roll, r_shifts, nrows, wr, ws,
                     slab, slab_fix)
-
-                def offs_rows(rows, m):
-                    # dense offsets for a surface-sized row subset,
-                    # premasked like the dense path's uniform offsets
-                    if uniform_offs:
-                        o = m[:, :, None] * noffs[None, :, :]
-                        if scaled:
-                            o = o * sc0[rows][:, None, None]
-                        return o
-                    return noffs[rows]
-
-                def run_bulk(full, cell_fields, extra):
-                    return _run_slotwise(
-                        kernel, cell_fields,
-                        {n: full[n] for n in fields_in}, slot_gather,
-                        _make_offs_col(uniform_offs, noffs,
-                                       sc0 if scaled else None),
-                        mask_col, n_slots, extra)
-
-                def run_outer(full, extra):
-                    # the bulk's slot loop over the outer planes alone
-                    # (closed-form slab plans: unscaled uniform offsets)
-                    og, ob = _synth_prep(synth, L,
-                                         row_gidx=rp_take(row_gidx))
-                    return _run_slotwise(
-                        kernel, {n: rp_take(full[n]) for n in fields_in},
-                        {n: full[n] for n in fields_in}, rp_gather_j,
-                        _make_offs_col(uniform_offs, noffs, None),
-                        lambda j: _synth_col(synth, og, ob, j),
-                        n_slots, extra)
             else:
                 if nmask is None:
                     nmask = _synth_mask(synth, L, row_gidx=row_gidx)
@@ -3458,100 +2968,26 @@ class Grid:
                 # in their op_name, which telemetry.publish_scopes maps
                 # to the op names a profiler trace shows
                 state = list(state)
-                if overlap:
-                    # sends read only local rows: every round's
-                    # collective starts BEFORE the bulk kernel, with no
-                    # data dependency between them, so the scheduler
-                    # can fly the halos under the stencil compute
-                    # (async collective-permute) — the reference's
-                    # solve-inner-while-messages-fly overlap
-                    # (dccrg.hpp:5046-5413, 2d.cpp:327-343)
-                    with jax.named_scope("dccrg.exchange"):
-                        payloads = [
-                            _halo_send(state[j], send_rs[xi * n_t + t],
-                                       None if deltas is None else deltas[t],
-                                       axis, n_dev)
-                            for xi, j in enumerate(exch_idx)
-                            for t in range(n_t)
-                        ]
-                    # bulk pass on pre-exchange state: rows
-                    # [0, n_inner) read no ghosts, so their results
-                    # are final; outer rows are redone below
-                    with jax.named_scope("dccrg.bulk"):
-                        full = dict(statics)
-                        full.update(zip(fields_out, state))
-                        cell_fields = {n: full[n][:L] for n in fields_in}
-                        if slotwise:
-                            result = run_bulk(full, cell_fields, extra)
-                        else:
-                            nbr_fields = {n: gather_nbr(full[n])
-                                          for n in fields_in}
-                            result = kernel(cell_fields, nbr_fields, noffs,
-                                            nmask, *extra)
-                    # land the halos, then redo just the outer rows
-                    # (with ghost-split, only the rows feeding the
-                    # exchanged fields, scattering only the outputs
-                    # whose declared ghost reads those fields)
+                if n_dev > 1:
                     with jax.named_scope("dccrg.exchange"):
                         for xi, j in enumerate(exch_idx):
-                            fl = state[j]
-                            for t in range(n_t):
-                                fl = _halo_scatter(
-                                    fl, recv_rs[xi * n_t + t],
-                                    payloads[xi * n_t + t], R)
-                            state[j] = fl.at[R - 1].set(0)
-                    if o_slab:
-                        with jax.named_scope("dccrg.repass"):
-                            full = dict(statics)
-                            full.update(zip(fields_out, state))
-                            o_res = run_outer(full, extra)
-                            for n in repass:
-                                result[n] = rp_put(
-                                    result[n],
-                                    o_res[n].astype(result[n].dtype))
-                    elif o_tabs:
-                        with jax.named_scope("dccrg.repass"):
-                            full = dict(statics)
-                            full.update(zip(fields_out, state))
-                            cell_fields = {n: full[n][:L]
-                                           for n in fields_in}
-                            om = mask_rows(orc) if slotwise else nmask[orc]
-                            o_cell = {n: cell_fields[n][orc]
+                            state[j] = exchange_one(state[j], xi)
+                with jax.named_scope("dccrg.bulk"):
+                    full = dict(statics)
+                    full.update(zip(fields_out, state))
+                    cell_fields = {n: full[n][:L] for n in fields_in}
+                    if slotwise:
+                        result = _run_slotwise(
+                            kernel, cell_fields,
+                            {n: full[n] for n in fields_in}, slot_gather,
+                            _make_offs_col(uniform_offs, noffs,
+                                           sc0 if scaled else None),
+                            mask_col, n_slots, extra)
+                    else:
+                        nbr_fields = {n: gather_nbr(full[n])
                                       for n in fields_in}
-                            o_nbr = {}
-                            for n in fields_in:
-                                g = full[n][onr]
-                                if use_roll:
-                                    # mirror _make_nbr_gather's
-                                    # mask-zeroing
-                                    mexp = om.reshape(
-                                        om.shape + (1,) * (g.ndim - 2))
-                                    g = jnp.where(mexp, g,
-                                                  jnp.zeros((), g.dtype))
-                                o_nbr[n] = g
-                            o_offs = (offs_rows(orc, om) if slotwise
-                                      else noffs[orc])
-                            o_res = kernel(o_cell, o_nbr, o_offs, om, *extra)
-                            for n in repass:
-                                result[n] = result[n].at[orow].set(
-                                    o_res[n].astype(result[n].dtype),
-                                    mode="drop")
-                else:
-                    if n_dev > 1:
-                        with jax.named_scope("dccrg.exchange"):
-                            for xi, j in enumerate(exch_idx):
-                                state[j] = exchange_one(state[j], xi)
-                    with jax.named_scope("dccrg.bulk"):
-                        full = dict(statics)
-                        full.update(zip(fields_out, state))
-                        cell_fields = {n: full[n][:L] for n in fields_in}
-                        if slotwise:
-                            result = run_bulk(full, cell_fields, extra)
-                        else:
-                            nbr_fields = {n: gather_nbr(full[n])
-                                          for n in fields_in}
-                            result = kernel(cell_fields, nbr_fields, noffs,
-                                            nmask, *extra)
+                        result = kernel(cell_fields, nbr_fields, noffs,
+                                        nmask, *extra)
                 if split:
                     with jax.named_scope("dccrg.bulk"):
                         h_cell = {n: cell_fields[n][hrc] for n in fields_in}
@@ -3579,8 +3015,6 @@ class Grid:
             + ((P(axis),) if slab else (P(axis), P(axis)) if use_roll else ())
             + ((P(axis),) if scaled else ())
             + ((P(axis),) * 4 if split else ())
-            + ((P(axis),) if o_slab else (P(axis), P(axis)) if o_tabs
-               else ())
             + (P(axis),) * (n_static + n_out) + (P(),) * n_extra,
             out_specs=(P(axis),) * n_out,
             check_vma=False,

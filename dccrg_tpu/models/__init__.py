@@ -22,20 +22,20 @@ from . import mhd, vlasov
 mhd.register()
 vlasov.register()
 
-#: the zoo table: fleet-kernel name -> structured info (fields, ghost
-#: dependencies, conserved quantities, the model class name)
+#: the zoo table: fleet-kernel name -> structured info (fields,
+#: conserved quantities, the model class name)
 MODEL_ZOO = {
     "mhd": dict(mhd.ZOO_INFO),
     "vlasov": dict(vlasov.ZOO_INFO),
     "diffuse": {
         "kernel": "diffuse", "fields": ("rho",),
-        "ghost_deps": None, "conserved": ("rho",),
+        "conserved": ("rho",),
         "model": None,
         "description": "neighbor-coupling relaxation (fleet workhorse)",
     },
     "advect_x": {
         "kernel": "advect_x", "fields": ("rho",),
-        "ghost_deps": None, "conserved": ("rho",),
+        "conserved": ("rho",),
         "model": None,
         "description": "first-order upwind advection along +x",
     },
@@ -75,8 +75,7 @@ def ensure_registered() -> None:
 
 def available_models() -> list:
     """The zoo, one dict per registered kernel: ``name``, ``fields``,
-    ``ghost_deps`` (None = undeclared/conservative), ``conserved``
-    fields, the multi-device ``model`` class name and a one-line
-    description — the README model table's source of truth."""
+    ``conserved`` fields, the multi-device ``model`` class name and a
+    one-line description — the README model table's source of truth."""
     return [dict(info, name=name)
             for name, info in sorted(MODEL_ZOO.items())]

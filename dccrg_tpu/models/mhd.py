@@ -12,12 +12,9 @@ operator-split passes with **different ghost dependencies**:
   smoothing of B over face neighbors (the diffusive limit of
   constrained-transport cleaning) — reads ONLY the B fields' ghosts.
 
-That split is exactly what the per-field ghost-split overlap
-(``DCCRG_GHOST_SPLIT``, grid.py) consumes: each pass declares
-``ghost_deps`` and exchanges only its own subsystem, so the overlap
-outer re-pass recomputes the subsystem's rows instead of every outer
-row x every field (counted by ``Grid.last_overlap``, which
-bench/models_bench.py's ``outer_repass_rows_{full,split}`` keys read).
+Each pass exchanges only its own subsystem (``exchange_fields`` of
+``Grid.run_steps``): the other subsystem's ghosts are not read by that
+pass, so they do not move.
 
 Modeling notes (honest simplifications):
 
@@ -145,17 +142,13 @@ def _b_update(cell, nbr, offs, mask, lam):
 
 def make_mhd_kernel():
     """The fused fleet kernel (registry name ``"mhd"``): hydro flux
-    AND B cleaning every step, one parameter ``lam = dt/dx``.
-    Declares the per-field ghost split: hydro outputs read hydro
-    ghosts, B outputs read B ghosts."""
+    AND B cleaning every step, one parameter ``lam = dt/dx``."""
 
     def kernel(cell, nbr, offs, mask, lam):
         out = _hydro_update(cell, nbr, offs, mask, lam)
         out.update(_b_update(cell, nbr, offs, mask, lam))
         return out
 
-    kernel.ghost_deps = {**{n: MHD_HYDRO for n in MHD_HYDRO},
-                         **{n: MHD_BFIELD for n in MHD_BFIELD}}
     return kernel
 
 
@@ -163,25 +156,18 @@ def make_mhd_pass_kernels():
     """The operator-split pair ``(hydro_pass, b_pass)`` driving
     :class:`GridMHD`: each pass updates its subsystem and passes the
     other through IDENTITY, so a ``run_steps`` call exchanges only
-    the subsystem that changes — a proper subset of ``fields_out``,
-    which is what lets the ghost-split outer re-pass skip the frozen
-    subsystem's rows entirely."""
+    the subsystem that changes — a proper subset of ``fields_out``."""
 
     def hydro_pass(cell, nbr, offs, mask, lam):
         out = _hydro_update(cell, nbr, offs, mask, lam)
         out.update({n: cell[n] for n in MHD_BFIELD})
         return out
 
-    hydro_pass.ghost_deps = {**{n: MHD_HYDRO for n in MHD_HYDRO},
-                             **{n: () for n in MHD_BFIELD}}
-
     def b_pass(cell, nbr, offs, mask, lam):
         out = {n: cell[n] for n in MHD_HYDRO}
         out.update(_b_update(cell, nbr, offs, mask, lam))
         return out
 
-    b_pass.ghost_deps = {**{n: () for n in MHD_HYDRO},
-                         **{n: MHD_BFIELD for n in MHD_BFIELD}}
     return hydro_pass, b_pass
 
 
@@ -319,8 +305,6 @@ def register() -> None:
 ZOO_INFO = {
     "kernel": "mhd",
     "fields": MHD_ALL,
-    "ghost_deps": {**{n: MHD_HYDRO for n in MHD_HYDRO},
-                   **{n: MHD_BFIELD for n in MHD_BFIELD}},
     "conserved": MHD_ALL,
     "model": "GridMHD",
     "description": ("finite-volume ideal-MHD-style: Rusanov hydro "

@@ -294,8 +294,7 @@ class PoissonSolver:
         matvecs: gather, kernel, write-back), ``dccrg.dot`` (the global
         reductions), ``dccrg.update`` (alpha/beta, the vector updates and
         the selects on ``go``) and, on several devices, ``dccrg.exchange``
-        (the p0/p1 halo update) and ``dccrg.repass`` (the overlap's outer
-        re-pass)."""
+        (the p0/p1 halo update, before both matvecs)."""
         g = self.grid
         fields_in_fwd = ["p0", "ilen", "ctype", "scale"] + [
             n for pair in _F_NAMES for n in pair
@@ -309,43 +308,18 @@ class PoissonSolver:
             POISSON_NEIGHBORHOOD_ID, False)
         _s1, _f1, fused1, _nt1 = g._exchange_programs(POISSON_NEIGHBORHOOD_ID, 1)
         sx1, rx1 = g._pair_tables_device(POISSON_NEIGHBORHOOD_ID, ("p0",))
-        start2_j, finish2_j, fused2, _nt2 = g._exchange_programs(
-            POISSON_NEIGHBORHOOD_ID, 2)
+        _s2, _f2, fused2, _nt2 = g._exchange_programs(POISSON_NEIGHBORHOOD_ID, 2)
         sx2, rx2 = g._pair_tables_device(POISSON_NEIGHBORHOOD_ID, ("p0", "p1"))
         statics = tuple(g.data[n] for n in fields_in_fwd[1:])
         mask = self._solve_mask
         single = g.n_dev == 1
-        # the split-overlap treatment of the per-iteration matvecs
-        # (the step loop's DCCRG_GHOST_SPLIT discipline): start the
-        # p0/p1 halo collective, run both matvecs on PRE-exchange
-        # state — rows whose gather reads no refreshed ghost are
-        # final — land the halos, then re-run ONLY the rows feeding
-        # the exchanged field (grid._make_outer_repass). Accelerator-
-        # default like the step overlap (DCCRG_OVERLAP), ghost-split
-        # opt-out shared (DCCRG_GHOST_SPLIT=0 = this pre-PR program)
-        from ..grid import ghost_split_enabled
-
-        rp_fwd = rp_tr = None
-        if not single and g._use_overlap() and ghost_split_enabled():
-            rp_fwd = g._make_outer_repass(
-                self._fwd, tuple(fields_in_fwd), ("Ap0",),
-                POISSON_NEIGHBORHOOD_ID, ("p0",))
-            rp_tr = g._make_outer_repass(
-                self._tr, tuple(fields_in_tr), ("r1",),
-                POISSON_NEIGHBORHOOD_ID, ("p1",))
-        overlap = rp_fwd is not None and rp_tr is not None
-        rpf_fn, rpf_t = rp_fwd if overlap else (None, ())
-        rpt_fn, rpt_t = rp_tr if overlap else (None, ())
         nf, nt = len(fwd_tables), len(tr_tables)
         n1, n2 = len(sx1) + len(rx1), len(sx2) + len(rx2)
-        n_sx2 = len(sx2)
-        nrf, nrt = len(rpf_t), len(rpt_t)
         ns = len(statics)
         bindings = (*fwd_tables, *tr_tables, *sx1, *rx1, *sx2, *rx2,
-                    *rpf_t, *rpt_t, mask, *statics)
+                    mask, *statics)
         key = ("poisson_fused", self._fwd, self._tr, single,
-               nf, nt, n1, n2, ns, g.plan.L, g.plan.R,
-               overlap)
+               nf, nt, n1, n2, ns, g.plan.L, g.plan.R)
         prog = g._program_cache.get(key)
         if prog is not None:
             return prog, bindings
@@ -356,11 +330,8 @@ class PoissonSolver:
             tr_t = rest[nf:nf + nt]
             ex1 = rest[nf + nt:nf + nt + n1]
             ex2 = rest[nf + nt + n1:nf + nt + n1 + n2]
-            base = nf + nt + n1 + n2
-            rpf_tables = rest[base:base + nrf]
-            rpt_tables = rest[base + nrf:base + nrf + nrt]
-            mask = rest[base + nrf + nrt]
-            statics = rest[base + nrf + nrt + 1:]
+            mask = rest[nf + nt + n1 + n2]
+            statics = rest[nf + nt + n1 + n2 + 1:]
 
             def fwd(*args):
                 with jax.named_scope("dccrg.matvec"):
@@ -377,18 +348,6 @@ class PoissonSolver:
             def exchange2(p0, p1):
                 with jax.named_scope("dccrg.exchange"):
                     return fused2(*ex2, p0, p1)
-
-            def exchange2_start(p0, p1):
-                with jax.named_scope("dccrg.exchange"):
-                    return start2_j(*ex2[:n_sx2], p0, p1)
-
-            def exchange2_finish(bufs, p0, p1):
-                with jax.named_scope("dccrg.exchange"):
-                    return finish2_j(*ex2[n_sx2:], *bufs, p0, p1)
-
-            def repass(fn, tables, *args):
-                with jax.named_scope("dccrg.repass"):
-                    return fn(*tables, *args)
 
             def dot(a, b):
                 with jax.named_scope("dccrg.dot"):
@@ -416,21 +375,10 @@ class PoissonSolver:
 
             def body(s):
                 p0, p1 = s["p0"], s["p1"]
-                if overlap:
-                    # sends read local rows only: the collective flies
-                    # under both bulk matvecs, then only the refreshed
-                    # rows are redone (the ghost-split overlap)
-                    bufs = exchange2_start(p0, p1)
-                    (Ap0,) = fwd(p0, *statics, s["Ap0"])
-                    (Atp1,) = tr(p1, *statics, s["r1"])
-                    p0, p1 = exchange2_finish(bufs, p0, p1)
-                    (Ap0,) = repass(rpf_fn, rpf_tables, p0, *statics, Ap0)
-                    (Atp1,) = repass(rpt_fn, rpt_tables, p1, *statics, Atp1)
-                else:
-                    if not single:
-                        p0, p1 = exchange2(p0, p1)
-                    (Ap0,) = fwd(p0, *statics, s["Ap0"])
-                    (Atp1,) = tr(p1, *statics, s["r1"])
+                if not single:
+                    p0, p1 = exchange2(p0, p1)
+                (Ap0,) = fwd(p0, *statics, s["Ap0"])
+                (Atp1,) = tr(p1, *statics, s["r1"])
                 dot_p = dot(p1, Ap0)
                 with jax.named_scope("dccrg.update"):
                     go = (dot_p != 0) & (s["dot_r"] != 0)

@@ -70,8 +70,8 @@ def _moments(f, v, dv):
 def make_vlasov_kernel():
     """The fleet kernel (registry name ``"vlasov"``), one parameter
     ``dt``. ``Nv`` is read off the field shape, so one kernel serves
-    every payload width. Declares that EVERY output's ghost reads are
-    the two moments — the wide ``f`` is never read from neighbors."""
+    every payload width. Its only neighbor reads are the two moments —
+    the wide ``f`` is never read from neighbors."""
 
     def kernel(cell, nbr, offs, mask, dt):
         f = cell["f"].astype(_f32)               # [L, Nv]
@@ -107,7 +107,6 @@ def make_vlasov_kernel():
         rho2, ux2 = _moments(f2, v, dv)
         return {"f": f2, "rho": rho2, "ux": ux2}
 
-    kernel.ghost_deps = {n: VLASOV_EXCHANGE for n in VLASOV_FIELDS}
     return kernel
 
 
@@ -196,7 +195,6 @@ def register() -> None:
 ZOO_INFO = {
     "kernel": "vlasov",
     "fields": VLASOV_FIELDS,
-    "ghost_deps": {n: VLASOV_EXCHANGE for n in VLASOV_FIELDS},
     "conserved": ("rho",),
     "model": "GridVlasov",
     "description": ("hybrid-Vlasov-style: wide [Nv] per-cell velocity "

@@ -4,10 +4,9 @@ examples/game_of_life.cpp): start the remote-copy update, do the work
 that doesn't need fresh ghosts, finish receives before reading
 neighbors, finish sends before overwriting local state — the
 reference's solve-inner-while-messages-fly structure, expressed
-through the same four-call API. (On device, the fused
-``Grid.run_steps`` + ``DCCRG_OVERLAP`` path performs this overlap
-inside one XLA program; this example demonstrates the HOST-side
-split-phase parity API.)
+through the same four-call API. (The fused ``Grid.run_steps`` path
+exchanges first and then steps every cell inside one XLA program;
+this example demonstrates the HOST-side split-phase parity API.)
 
 The board is verified against a pure-numpy life simulation every turn,
 and per-turn speed statistics are printed like the reference's.

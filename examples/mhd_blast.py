@@ -5,10 +5,7 @@
    ONLY the hydro fields' ghosts, the CT/divergence-cleaning pass
    ONLY the B fields' — and conservation of mass/momentum/energy/B
    is checked against the integrity layer's drift tolerance.
-2. The per-field ghost-split overlap (DCCRG_GHOST_SPLIT) is compared
-   against the full outer re-pass: BITWISE-identical state, strictly
-   fewer recomputed outer row slots (the counts are printed).
-3. The same physics serves as a FLEET kernel: a mixed mini-fleet
+2. The same physics serves as a FLEET kernel: a mixed mini-fleet
    (advect_x + mhd + vlasov — three buckets under one scheduler)
    runs to completion with every job's digest bitwise equal to its
    solo ``Grid.run_steps`` run.
@@ -37,7 +34,7 @@ import numpy as np  # noqa: E402
 
 
 def main():
-    from dccrg_tpu import checkpoint, integrity
+    from dccrg_tpu import integrity
     from dccrg_tpu.fleet import FleetJob, run_solo
     from dccrg_tpu.models import GridMHD
     from dccrg_tpu.models.mhd import MHD_ALL
@@ -58,25 +55,7 @@ def main():
               f"<= tol {tol:.2e}: {status})")
         assert drift <= tol, name
 
-    # -- 2. ghost-split vs full outer re-pass -------------------------
-    os.environ["DCCRG_OVERLAP"] = "1"  # CPU default is off
-    digests, rows = {}, {}
-    for split in ("0", "1"):
-        os.environ["DCCRG_GHOST_SPLIT"] = split
-        g = GridMHD(n=8, nz=40)
-        g.run(5, dt=0.01)
-        digests[split] = checkpoint.state_digest(g.grid)
-        ov = g.grid.last_overlap
-        rows[split] = (ov["mode"], ov["rows_split"], ov["rows_full"])
-    os.environ.pop("DCCRG_OVERLAP")
-    os.environ.pop("DCCRG_GHOST_SPLIT")
-    assert digests["0"] == digests["1"], "ghost-split parity broken"
-    print(f"ghost split: bitwise parity OK; cleaning-pass outer "
-          f"re-pass {rows['0'][1]} -> {rows['1'][1]} row slots "
-          f"(mode {rows['0'][0]} -> {rows['1'][0]})")
-    assert rows["1"][1] < rows["0"][1]
-
-    # -- 3. the mixed mini-fleet --------------------------------------
+    # -- 2. the mixed mini-fleet --------------------------------------
     jobs = [FleetJob(f"{k}0", kernel=k, length=(6, 6, 6), n_steps=8,
                      seed=7, checkpoint_every=4)
             for k in ("advect_x", "mhd", "vlasov")]

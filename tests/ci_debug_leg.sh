@@ -59,8 +59,8 @@
 # Also runs a model-zoo smoke leg under DCCRG_DEBUG=1: an MHD 8^3
 # run (conservation pinned) plus the MHD-schema GridFuzzer leg, so
 # every mutation's post-commit verify_all runs over the multi-field
-# schema, and one ghost-split parity case (split vs full outer
-# re-pass bitwise, strictly fewer recomputed row slots).
+# schema, and the MHD pass pair on the 8-device mesh (each pass
+# exchanging only its own subsystem) against one device.
 #
 # Also runs a warm-start smoke leg under DCCRG_DEBUG=1: a cold serve
 # manifests its compile-cache records and a fresh pool serves every
@@ -149,7 +149,7 @@ PYEOF
 env JAX_PLATFORMS=cpu python -m pytest -q \
     "tests/test_models.py::test_mhd_conservation" \
     "tests/test_models.py::test_mhd_schema_fuzz_leg" \
-    "tests/test_models.py::test_ghost_split_bitwise_and_strictly_fewer_rows" \
+    "tests/test_models.py::test_mhd_pass_pair_mesh_matches_one_device" \
     --dccrg-debug -p no:cacheprovider "$@"
 env JAX_PLATFORMS=cpu python -m pytest -q \
     "tests/test_warmstart.py::test_cold_run_manifests_and_warm_run_hits" \

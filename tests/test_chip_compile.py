@@ -63,17 +63,7 @@ def compile_grid_step(grid, kernel, devices):
     mesh of ``devices`` and rebuilds the program over it: the cached
     tables and the fields only lend their shapes."""
     args = (kernel, ["density", "vx", "vy"], ["density"])
-    # the chip overlaps the halo exchange by default: upload the
-    # overlap's tables as well
-    prev = os.environ.get("DCCRG_OVERLAP")
-    os.environ["DCCRG_OVERLAP"] = "1"
-    try:
-        grid.compile_step_loop(*args, n_extra=1)
-    finally:
-        if prev is None:
-            os.environ.pop("DCCRG_OVERLAP")
-        else:
-            os.environ["DCCRG_OVERLAP"] = prev
+    grid.compile_step_loop(*args, n_extra=1)
     grid.mesh = Mesh(np.array(devices), ("dev",))
     grid._program_cache.clear()
     fn, tables, static_in = grid.compile_step_loop(*args, n_extra=1)
@@ -145,16 +135,13 @@ def test_xla_step_program_compiles_128(topo):
 def test_xla_step_program_compiles_128_mesh4(topo):
     """The step program on a 2x2 v5e: four z slabs of whole planes take
     the slab gather, so the bulk pass holds no scatter (the flat roll's
-    fix-ups were one per slot and field), and the overlap's outer
-    re-pass recomputes whole planes with no gather or scatter (the
-    element re-pass gathered every outer row per slot and field)."""
+    fix-ups were one per slot and field). The halo exchange runs before
+    the bulk pass, and no outer rows are recomputed after it."""
     compiled = advection_step_128(topo.devices[:4])
     assert fits_hbm(compiled)
     lines = compiled.as_text().splitlines()
     bulk_scatters = [ln for ln in lines
                      if " scatter(" in ln and "dccrg.bulk" in ln]
     assert not bulk_scatters
-    repass_elements = [ln for ln in lines if "dccrg.repass" in ln
-                       and (" gather(" in ln or " scatter(" in ln)]
-    assert not repass_elements
-    assert any("dccrg.repass" in ln for ln in lines)
+    assert any("dccrg.exchange" in ln for ln in lines)
+    assert not any("dccrg.repass" in ln for ln in lines)

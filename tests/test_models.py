@@ -1,15 +1,13 @@
-"""Model zoo: multi-field MHD + wide-payload Vlasov workloads, the
-per-field ghost-split exchange, and mixed-kernel fleet serving.
+"""Model zoo: multi-field MHD + wide-payload Vlasov workloads, their
+multi-device steps, and mixed-kernel fleet serving.
 
 The acceptance pins of the zoo contract:
 
 - both new models ride ``Grid.run_steps``, ``ResilientRunner``
   (rollback reconverges bitwise), per-job fleet checkpoints and the
   fuzz oracle with NO changes to those layers' public APIs;
-- the per-field ghost-split overlap is bitwise identical to the full
-  outer re-pass, recomputes strictly fewer outer row slots when a
-  step exchanges a proper field subset (counted), and is opt-out
-  (``DCCRG_GHOST_SPLIT=0`` = the pre-split program);
+- on a mesh each model (and the Poisson fused solve) matches one
+  device, while its steps exchange only a proper field subset;
 - jobs across >= 3 distinct kernels serve concurrently under one
   scheduler + SLO policy with per-slot fault isolation pinned
   bitwise vs solo runs, and a deadline job can shed a best-effort
@@ -24,7 +22,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from dccrg_tpu import checkpoint, faults, integrity, telemetry
+from dccrg_tpu import faults, integrity, telemetry
 from dccrg_tpu.fleet import (FLEET_KERNELS, FleetJob, _jobs_from_spec,
                              run_solo)
 from dccrg_tpu.fuzz import GridFuzzer
@@ -45,8 +43,6 @@ def test_zoo_registry_surface():
     zoo = {m["name"]: m for m in available_models()}
     assert {"mhd", "vlasov", "diffuse", "advect_x"} <= set(zoo)
     assert set(zoo["mhd"]["fields"]) == set(MHD_ALL)
-    assert zoo["mhd"]["ghost_deps"]["bx"] == MHD_BFIELD
-    assert zoo["mhd"]["ghost_deps"]["rho"] == MHD_HYDRO
     assert set(zoo["vlasov"]["conserved"]) == {"rho"}
     # registration happened on import: the fleet can name both
     assert "mhd" in FLEET_KERNELS and "vlasov" in FLEET_KERNELS
@@ -158,71 +154,58 @@ def test_vlasov_resilient_runner_rollback_bitwise(tmp_path):
         assert a.tobytes() == b.tobytes(), n
 
 
-# -- per-field ghost-split overlap ------------------------------------
+# -- the multi-device step against one device -------------------------
 
-def _mhd_multidev(monkeypatch, split):
-    """8x8x40 block slabs over the 8-device mesh: thick enough that
-    the overlap heuristic engages (the test_overlap geometry)."""
-    monkeypatch.setenv("DCCRG_OVERLAP", "1")
-    monkeypatch.setenv("DCCRG_GHOST_SPLIT", "1" if split else "0")
-    return GridMHD(n=8, nz=40)
+def _one_device():
+    import jax
 
+    from dccrg_tpu.grid import default_mesh
 
-def test_ghost_split_bitwise_and_strictly_fewer_rows(monkeypatch):
-    """THE acceptance pin: split vs full outer re-pass is bitwise
-    identical on the MHD model, and a step exchanging a proper field
-    subset recomputes STRICTLY fewer outer row slots (counted). The
-    split=False leg doubles as the negative pin: the pre-split
-    program — full outer tables, full repass field set, no
-    gsplit-keyed program anywhere."""
-    digests, counts = {}, {}
-    for split in (False, True):
-        m = _mhd_multidev(monkeypatch, split)
-        hydro, bpass = make_mhd_pass_kernels()
-        lam = jnp.float32(0.01 * m.n)
-        per_pass = []
-        for kern, exch in ((hydro, MHD_HYDRO), (bpass, MHD_BFIELD)):
-            m.grid.run_steps(kern, MHD_ALL, MHD_ALL, 5,
-                             exchange_fields=exch, extra_args=(lam,))
-            per_pass.append(dict(m.grid.last_overlap))
-        digests[split] = checkpoint.state_digest(m.grid)
-        counts[split] = per_pass
-        if not split:
-            # the negative pin: the opt-out compiled the pre-split
-            # program (full repass set, no gsplit program keys)
-            assert m.grid.last_overlap["repass_fields"] == MHD_ALL
-            for key in m.grid._program_cache:
-                assert not any(
-                    isinstance(p, tuple) and p and p[0] == "gsplit"
-                    for p in key if isinstance(p, tuple)), key
-    assert digests[False] == digests[True]
-    # split off: the full re-pass recomputes every field at every
-    # outer row in both passes
-    for ov in counts[False]:
-        assert ov["mode"] == "full"
-        assert ov["rows_split"] == ov["rows_full"] > 0
-    # split on: each pass re-runs only its own subsystem's slots
-    hydro_ov, b_ov = counts[True]
-    assert hydro_ov["mode"] == "split" and b_ov["mode"] == "split"
-    assert set(hydro_ov["repass_fields"]) == set(MHD_HYDRO)
-    assert set(b_ov["repass_fields"]) == set(MHD_BFIELD)
-    assert 0 < hydro_ov["rows_split"] < hydro_ov["rows_full"]
-    assert 0 < b_ov["rows_split"] < b_ov["rows_full"]
+    return default_mesh(jax.devices()[:1])
 
 
-def test_ghost_split_vlasov_parity_and_shared_fallback(monkeypatch):
-    """Vlasov's declared deps cover every exchanged field at every
-    outer row, so the split saves nothing — it must fall back to the
-    SHARED pre-split program (mode 'full'), bitwise both ways."""
-    digests = {}
-    for split in (False, True):
-        monkeypatch.setenv("DCCRG_OVERLAP", "1")
-        monkeypatch.setenv("DCCRG_GHOST_SPLIT", "1" if split else "0")
-        v = GridVlasov(n=8, nz=40, nv=8)
+def _mhd_pass_pair(mesh, exchange_all=False):
+    """Five hydro steps, then five B steps, each pass exchanging only
+    its own subsystem (or every field); the cells of every field."""
+    m = GridMHD(n=8, nz=40, mesh=mesh)
+    hydro, bpass = make_mhd_pass_kernels()
+    lam = jnp.float32(0.01 * m.n)
+    for kern, exch in ((hydro, MHD_HYDRO), (bpass, MHD_BFIELD)):
+        m.grid.run_steps(kern, MHD_ALL, MHD_ALL, 5,
+                         exchange_fields=MHD_ALL if exchange_all else exch,
+                         extra_args=(lam,))
+    return m.grid.n_dev, {n: np.asarray(m.grid.get(n, m.grid.plan.cells))
+                          for n in MHD_ALL}
+
+
+def test_mhd_pass_pair_mesh_matches_one_device():
+    """The operator-split pair on the 8-device mesh: exchanging only a
+    pass's own subsystem (the other one's ghosts go stale; that pass
+    does not read them) is bitwise the same as exchanging every field,
+    and matches the same grid on one device to rounding (the two
+    programs contract the flux arithmetic differently: a few cells
+    differ in the last bit)."""
+    n8, got = _mhd_pass_pair(None)
+    _, full = _mhd_pass_pair(None, exchange_all=True)
+    n1, want = _mhd_pass_pair(_one_device())
+    assert (n8, n1) == (8, 1)
+    for n in MHD_ALL:
+        assert got[n].tobytes() == full[n].tobytes(), n
+        np.testing.assert_allclose(got[n], want[n], rtol=2e-6, atol=2e-6,
+                                   err_msg=n)
+
+
+def test_vlasov_mesh_matches_one_device():
+    """GridVlasov on the 8-device mesh (only the moments exchange)
+    steps bitwise like one device, the wide payload included."""
+    out = []
+    for mesh in (None, _one_device()):
+        v = GridVlasov(n=8, nz=40, nv=8, mesh=mesh)
         v.run(4, dt=0.04)
-        assert v.grid.last_overlap["mode"] == "full"
-        digests[split] = checkpoint.state_digest(v.grid)
-    assert digests[False] == digests[True]
+        cells = v.grid.plan.cells
+        out.append(b"".join(np.asarray(v.grid.get(n, cells)).tobytes()
+                            for n in VLASOV_FIELDS))
+    assert out[0] == out[1]
 
 
 def test_vlasov_wide_payload_never_exchanges(monkeypatch):
@@ -249,29 +232,32 @@ def test_vlasov_wide_payload_never_exchanges(monkeypatch):
     assert ghost_bytes("f") != f_before
 
 
-# -- Poisson fused-CG split-overlap -----------------------------------
+# -- Poisson fused solve on a mesh ------------------------------------
 
-def test_poisson_fused_cg_split_overlap_bitwise(monkeypatch):
-    """The fused-CG matvec under the split-overlap treatment (halo
-    started, bulk matvec on pre-exchange state, refreshed rows
-    redone) converges to the bitwise-identical solution in the same
-    iteration count as the sequential pre-split program."""
+def test_poisson_fused_solve_mesh_matches_one_device():
+    """The fused BiCG solve on 4 devices (p0/p1 exchanged before both
+    matvecs every iteration) takes the same iteration count as on one
+    device and lands on the same solution; only the global dots'
+    summation order differs (float64)."""
+    import jax
+
+    from dccrg_tpu.grid import default_mesh
     from dccrg_tpu.models.poisson import PoissonSolver
 
     out = {}
-    for split in (False, True):
-        monkeypatch.setenv("DCCRG_OVERLAP", "1")
-        monkeypatch.setenv("DCCRG_GHOST_SPLIT", "1" if split else "0")
-        s = PoissonSolver(length=(8, 8, 8), dtype=jnp.float64)
+    for n_dev in (4, 1):
+        s = PoissonSolver(length=(8, 8, 8), dtype=jnp.float64,
+                          mesh=default_mesh(jax.devices()[:n_dev]))
+        assert s.grid.n_dev == n_dev
         s.set_rhs_from(
             lambda x, y, z: np.cos(2 * np.pi * x / 8)
             + np.sin(2 * np.pi * y / 8))
-        s.solve(rtol=1e-8)
-        keys = [k for k in s.grid._program_cache
-                if k[0] == "poisson_fused"]
-        assert [k[-1] for k in keys] == [split]  # engaged iff split
-        out[split] = np.asarray(s.solution())
-    assert out[False].tobytes() == out[True].tobytes()
+        info = s.solve(rtol=1e-8)
+        out[n_dev] = info["iterations"], np.asarray(s.solution())
+    assert out[4][0] == out[1][0] > 0
+    scale = np.abs(out[1][1]).max()
+    np.testing.assert_allclose(out[4][1], out[1][1], rtol=0,
+                               atol=1e-12 * scale)
 
 
 # -- mixed-kernel fleet serving ---------------------------------------

@@ -22,10 +22,9 @@ import jax.numpy as jnp
 from dccrg_tpu.grid import DEFAULT_NEIGHBORHOOD_ID, Grid, SlotwiseKernel
 
 
-def _mk(monkeypatch, *, roll, refine=False, overlap=False,
+def _mk(monkeypatch, *, roll, refine=False,
         length=(8, 8, 40), periodic=(True, True, False)):
     monkeypatch.setenv("DCCRG_ROLL_STENCIL", "1" if roll else "0")
-    monkeypatch.setenv("DCCRG_OVERLAP", "1" if overlap else "0")
     g = (
         Grid(cell_data={"v": jnp.float32, "w": jnp.float32})
         .set_initial_length(length)
@@ -91,11 +90,11 @@ def test_apply_stencil_matches_dense(monkeypatch, roll, refine):
 
 
 @pytest.mark.parametrize("roll", [False, True])
-@pytest.mark.parametrize("overlap", [False, True])
-def test_run_steps_matches_dense(monkeypatch, roll, overlap):
-    """Slot-wise fused step loop == dense fused step loop, bitwise,
-    with and without the overlapped (inner/outer) execution."""
-    g = _mk(monkeypatch, roll=roll, overlap=overlap)
+@pytest.mark.parametrize("refine", [False, True])
+def test_run_steps_matches_dense(monkeypatch, roll, refine):
+    """Slot-wise fused step loop == dense fused step loop, bitwise, on
+    both gather modes and with the AMR split (hard-rows) pass."""
+    g = _mk(monkeypatch, roll=roll, refine=refine)
     cells = g.plan.cells
     v0 = g.get("v", cells).copy()
     g.run_steps(_dense_kern, ["v", "w"], ["v"], 2)
@@ -273,91 +272,72 @@ def _xyz_slot_kern(payload, reach=1):
     return SlotwiseKernel(init, slot, finish)
 
 
-def _stepped(monkeypatch, n_dev, length, periodic, overlap, payload, op,
-             reach=1):
-    """Step the grid; returns its stepped fields, the slot gathers whose
-    ``dccrg_slot_gather_programs_total`` rose, and the outer re-pass
-    gathers whose ``dccrg_repass_programs_total`` rose."""
+def _stepped(n_dev, length, periodic, payload, op, reach=1):
+    """Step the grid; returns its stepped fields and the slot gathers
+    whose ``dccrg_slot_gather_programs_total`` rose."""
     from dccrg_tpu import telemetry
 
-    monkeypatch.setenv("DCCRG_OVERLAP", "1" if overlap else "0")
     g = _mesh_grid(n_dev, length, periodic, payload, reach)
     ins = ("v", "w") + (("p",) if payload else ())
     outs = ("v",) + (("p",) if payload else ())
     kern = _xyz_slot_kern(payload, reach)
     reg = telemetry.registry()
-    counters = (("dccrg_slot_gather_programs_total",
-                 ("roll3d", "slab3d", "roll_fixup", "table")),
-                ("dccrg_repass_programs_total", ("slab3d", "table")))
-    counts = {(c, k): reg.counter_value(c, gather=k)
-              for c, ks in counters for k in ks}
+    name = "dccrg_slot_gather_programs_total"
+    kinds = ("roll3d", "slab3d", "roll_fixup", "table")
+    counts = {k: reg.counter_value(name, gather=k) for k in kinds}
     if op == "run_steps":
         g.run_steps(kern, ins, outs, 3)
     else:
         for _ in range(2):
             g.update_copies_of_remote_neighbors()
             g.apply_stencil(kern, ins, outs)
-    rose = [(c, k) for (c, k), n in counts.items()
-            if reg.counter_value(c, gather=k) > n]
-    built = {k for c, k in rose if c == counters[0][0]}
-    repassed = {k for c, k in rose if c == counters[1][0]}
-    if op == "run_steps" and n_dev > 1:
-        assert g.last_overlap["mode"] == ("full" if overlap else "off")
-        assert g.last_overlap["repass_gather"] == (
-            next(iter(repassed)) if overlap else None)
+    built = {k for k, n in counts.items()
+             if reg.counter_value(name, gather=k) > n}
     cells = g.plan.cells
-    return {n: g.get(n, cells) for n in outs}, built, repassed
+    return {n: g.get(n, cells) for n in outs}, built
 
 
-@pytest.mark.parametrize("n_dev, periodic_z, overlap, payload, op, reach", [
-    (2, True, False, False, "run_steps", 1),
-    (2, False, True, False, "run_steps", 1),
-    (2, True, True, True, "run_steps", 1),
-    (4, True, True, False, "run_steps", 1),
-    (4, False, False, True, "run_steps", 1),
-    (4, False, True, True, "run_steps", 1),
-    (2, False, False, True, "apply_stencil", 1),
-    (4, True, False, False, "apply_stencil", 1),
-    (2, True, True, False, "run_steps", 2),
-    (4, True, True, True, "run_steps", 2),
-    (4, False, True, False, "run_steps", 2),
+@pytest.mark.parametrize("n_dev, periodic_z, payload, op, reach", [
+    (2, True, False, "run_steps", 1),
+    (2, False, False, "run_steps", 1),
+    (2, True, True, "run_steps", 1),
+    (4, True, False, "run_steps", 1),
+    (4, False, True, "run_steps", 1),
+    (2, False, True, "apply_stencil", 1),
+    (4, True, False, "apply_stencil", 1),
+    (2, True, False, "run_steps", 2),
+    (4, True, True, "run_steps", 2),
+    (4, False, False, "run_steps", 2),
+    (2, False, True, "apply_stencil", 2),
+    (4, True, False, "apply_stencil", 2),
 ])
-def test_slab_gather_matches_one_device(monkeypatch, n_dev, periodic_z,
-                                        overlap, payload, op, reach):
+def test_slab_gather_matches_one_device(n_dev, periodic_z, payload, op,
+                                        reach):
     """A multi-device closed-form plan whose slabs are whole z planes
     takes the slab gather (rolls and copies of whole planes, in-plane
     rolls) and steps bitwise like the same grid on one device, with z
-    periodic or walled, overlap on or off, and a [L, W] payload field.
-    With the overlap on, the outer re-pass recomputes whole planes too.
-    The 5 x 3 planes make L a bucket above the local rows, so pad rows
-    exist. Reach 2 (z offsets +-1, +-2) makes two outer planes per slab
-    side, over 32 planes so that every device keeps inner planes."""
+    periodic or walled and a [L, W] payload field. The 5 x 3 planes
+    make L a bucket above the local rows, so pad rows exist. Reach 2
+    (z offsets +-1, +-2) makes two outer planes per slab side, over 32
+    planes so that every device keeps inner planes."""
     length, periodic = (5, 3, 16 * reach), (True, False, periodic_z)
-    want, _, _ = _stepped(monkeypatch, 1, length, periodic, overlap, payload,
-                          op, reach)
-    got, built, repassed = _stepped(monkeypatch, n_dev, length, periodic,
-                                    overlap, payload, op, reach)
+    want, _ = _stepped(1, length, periodic, payload, op, reach)
+    got, built = _stepped(n_dev, length, periodic, payload, op, reach)
     assert built == {"slab3d"}
-    assert repassed == ({"slab3d"} if overlap else set())
     for n in want:
         np.testing.assert_array_equal(got[n], want[n])
 
 
-@pytest.mark.parametrize("n_dev, length, overlap", [
-    (8, (8, 6, 4), False),  # half-plane slabs (all rows outer)
-    (3, (5, 3, 16), True),  # 16 planes over 3 devices: unequal slabs
+@pytest.mark.parametrize("n_dev, length", [
+    (8, (8, 6, 4)),  # half-plane slabs (all rows outer)
+    (3, (5, 3, 16)),  # 16 planes over 3 devices: unequal slabs
 ])
-def test_slab_gather_falls_back_off_plane_slabs(monkeypatch, n_dev, length,
-                                                overlap):
+def test_slab_gather_falls_back_off_plane_slabs(n_dev, length):
     """Slabs that are not whole, equal z planes keep the flat roll with
-    its fixup scatter and the element-gather outer re-pass, and still
-    step bitwise like one device."""
+    its fixup scatter, and still step bitwise like one device."""
     periodic = (True, False, True)
-    want, _, _ = _stepped(monkeypatch, 1, length, periodic, overlap, True,
-                          "run_steps")
-    got, built, repassed = _stepped(monkeypatch, n_dev, length, periodic,
-                                    overlap, True, "run_steps")
+    want, _ = _stepped(1, length, periodic, True, "run_steps")
+    got, built = _stepped(n_dev, length, periodic, True, "run_steps")
     assert built == {"roll_fixup"}
-    assert repassed == ({"table"} if overlap else set())
     for n in want:
         np.testing.assert_array_equal(got[n], want[n])

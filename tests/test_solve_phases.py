@@ -56,21 +56,15 @@ def _stripped(text):
                   flags=re.M | re.S)
 
 
-@pytest.mark.parametrize("n_dev, overlap, want", [
-    (1, None, {"dccrg.matvec", "dccrg.dot", "dccrg.update"}),
-    (4, "1", {"dccrg.matvec", "dccrg.dot", "dccrg.update", "dccrg.exchange",
-              "dccrg.repass"}),
+@pytest.mark.parametrize("n_dev, want", [
+    (1, {"dccrg.matvec", "dccrg.dot", "dccrg.update"}),
+    (4, {"dccrg.matvec", "dccrg.dot", "dccrg.update", "dccrg.exchange"}),
 ])
-def test_solve_publishes_its_phase_table(tmp_path, monkeypatch, n_dev,
-                                         overlap, want):
+def test_solve_publishes_its_phase_table(tmp_path, n_dev, want):
     """Under a profiler session the first solve publishes the table of
     ``jit_dccrg_poisson_solve``; it covers every instruction of the
-    compiled module, and on four devices with the overlap on the
-    exchange and re-pass scopes show too."""
-    if overlap is None:
-        monkeypatch.delenv("DCCRG_OVERLAP", raising=False)
-    else:
-        monkeypatch.setenv("DCCRG_OVERLAP", overlap)
+    compiled module, and on four devices the exchange scope shows
+    too."""
     s = _solver(n_dev)
     s.solve(max_iterations=0)  # compiled outside the session
     jax.profiler.start_trace(str(tmp_path))

@@ -14,7 +14,7 @@ from dccrg_tpu.models.advection import GridAdvection
 
 pytestmark = pytest.mark.telemetry
 
-SCOPES = {"dccrg.exchange", "dccrg.bulk", "dccrg.repass", "dccrg.apply"}
+SCOPES = {"dccrg.exchange", "dccrg.bulk", "dccrg.apply"}
 INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = ", re.M)
 
 
@@ -52,19 +52,14 @@ def _module_text(solver, dt):
     return fn.lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("n_dev, overlap, want", [
-    (4, "1", SCOPES),
-    (1, None, SCOPES - {"dccrg.exchange", "dccrg.repass"}),
+@pytest.mark.parametrize("n_dev, want", [
+    (4, SCOPES),
+    (1, SCOPES - {"dccrg.exchange"}),
 ])
-def test_published_table_maps_every_op(tmp_path, monkeypatch, n_dev,
-                                       overlap, want):
+def test_published_table_maps_every_op(tmp_path, n_dev, want):
     """The table covers every instruction of the compiled
-    ``jit_dccrg_step_loop`` module; with the overlap on four devices all
-    four scopes show, and one device has no exchange or re-pass."""
-    if overlap is None:
-        monkeypatch.delenv("DCCRG_OVERLAP", raising=False)
-    else:
-        monkeypatch.setenv("DCCRG_OVERLAP", overlap)
+    ``jit_dccrg_step_loop`` module; on four devices all three scopes
+    show, and one device has no exchange."""
     solver, dt = _stepped_under_profiler(tmp_path, n_dev)
     table = telemetry.program_scopes()["jit_dccrg_step_loop"]
     assert set(INSTRUCTION.findall(_module_text(solver, dt))) == set(table)
@@ -87,13 +82,14 @@ def test_scope_table_takes_the_outermost_dccrg_scope():
     text = "\n".join([
         "HloModule jit_dccrg_step_loop, is_scheduled=true",
         '  %fusion.3 = f32[8]{0} fusion(%p), kind=kLoop, calls=%c, '
-        'metadata={op_name="jit(dccrg_step_loop)/while/body/dccrg.repass/'
+        'metadata={op_name="jit(dccrg_step_loop)/while/body/dccrg.exchange/'
         'dccrg.bulk/add" stack_frame_id=4}',
         '  ROOT %copy.1 = f32[8]{0} copy(%fusion.3)',
         '  %add.2 = s32[] add(%a, %b), metadata={op_name="jit(f)/while/body/add"}',
     ])
     assert telemetry.scope_table(text) == {
-        "fusion.3": "dccrg.repass", "copy.1": "unscoped", "add.2": "unscoped"}
+        "fusion.3": "dccrg.exchange", "copy.1": "unscoped",
+        "add.2": "unscoped"}
 
 
 @pytest.mark.parametrize("n_dev, want", [
