@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import telemetry
-from ..grid import DEFAULT_NEIGHBORHOOD_ID, Grid
+from ..grid import Grid, SlotwiseKernel
 from ..dense import DenseGrid
 from ..neighbors import face_masks, make_neighborhood
 
@@ -82,41 +82,42 @@ _GEOMETRY_FIELDS = [n for pair in _F_NAMES for n in pair] + ["scale", "ctype", "
 
 def _matvec_kernel(transpose: bool):
     """A·p (or transpose(A)·p) over face neighbors
-    (poisson_solve.hpp:296-338 forward, :422-466 transpose)."""
+    (poisson_solve.hpp:296-338 forward, :422-466 transpose), one
+    stencil leg at a time: the plan's slot gather feeds each neighbor
+    column, so no [L, 6] neighbor stack is built."""
     src = "p1" if transpose else "p0"
 
-    def kernel(cell, nbr, offs, mask):
-        p_c = cell[src]
-        p_n = nbr[src]
-        faces = face_masks(cell["ilen"][:, None], nbr["ilen"], offs, mask)
+    def init(cell):
+        return cell["scale"] * cell[src]
+
+    def slot(acc, cell, nbr, offs, mask):
+        # nbr[name] is [L], offs [3] or [L, 3] (raw, gated by mask)
+        faces = face_masks(cell["ilen"], nbr["ilen"], offs, mask)
         if transpose:
             # transpose reads A[n, c]: the /4 averaging applies when
             # THIS cell is the finer side of n's face (:463-466)
-            finer = cell["ilen"][:, None] < nbr["ilen"]
+            finer = cell["ilen"] < nbr["ilen"]
         else:
             # finer face neighbors: 4 per direction, each weighted f/4
-            finer = nbr["ilen"] < cell["ilen"][:, None]
+            finer = nbr["ilen"] < cell["ilen"]
         w = jnp.where(finer, 0.25, 1.0) * (nbr["ctype"] != SKIP_CELL)
-        acc = cell["scale"] * p_c
+        p_n = nbr[src]
         for d, (face_pos, face_neg) in enumerate(faces):
             if transpose:
                 # neighbor's factor of the opposite direction (:436-455)
-                m_pos = nbr[_F_NAMES[d][1]]
-                m_neg = nbr[_F_NAMES[d][0]]
+                m_pos, m_neg = nbr[_F_NAMES[d][1]], nbr[_F_NAMES[d][0]]
             else:
-                m_pos = cell[_F_NAMES[d][0]][:, None]
-                m_neg = cell[_F_NAMES[d][1]][:, None]
-            acc = acc + jnp.sum(jnp.where(face_pos, m_pos * w * p_n, 0.0), axis=1)
-            acc = acc + jnp.sum(jnp.where(face_neg, m_neg * w * p_n, 0.0), axis=1)
-        return {"out": acc}
+                m_pos, m_neg = cell[_F_NAMES[d][0]], cell[_F_NAMES[d][1]]
+            acc = acc + jnp.where(face_pos, m_pos * w * p_n, 0.0)
+            acc = acc + jnp.where(face_neg, m_neg * w * p_n, 0.0)
+        return acc
 
-    def wrapped(cell, nbr, offs, mask):
-        out = kernel(cell, nbr, offs, mask)
+    def finish(acc, cell):
         # only solve cells carry the result; others stay 0
         return {("r1" if transpose else "Ap0"):
-                jnp.where(cell["ctype"] == SOLVE_CELL, out["out"], 0.0)}
+                jnp.where(cell["ctype"] == SOLVE_CELL, acc, 0.0)}
 
-    return wrapped
+    return SlotwiseKernel(init, slot, finish)
 
 
 class PoissonSolver:

@@ -9,8 +9,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from dccrg_tpu import telemetry
 from dccrg_tpu.dense import dense_mesh
-from dccrg_tpu.models.poisson import DensePoissonSolver, PoissonSolver
+from dccrg_tpu.models.poisson import (
+    POISSON_NEIGHBORHOOD_ID, DensePoissonSolver, PoissonSolver)
 
 
 def mesh1(n):
@@ -329,3 +331,66 @@ def test_periodic_solve_matches_fft_reference(n_dev):
     # the residual the loop tracks
     cond = 12 / (4 * np.sin(np.pi / n) ** 2)
     assert discrete_rel_error(got, want) < 2 * cond * rtol, info
+
+
+def _gather_programs():
+    """Slot-wise programs built since the registry's last reset, by the
+    kind of their neighbor gather."""
+    return {k: telemetry.registry().counter_value(
+        "dccrg_slot_gather_programs_total", gather=k)
+        for k in ("roll3d", "slab3d", "roll_fixup", "table")}
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_amr_matvec_adjoint_identity(n_dev):
+    """On a refined grid the two matvecs of the host loop are each
+    other's transpose: <q, A p> = <A^T q, p> for seeded p, q in float64.
+    The hybrid plan runs the table slot gather plus the hard-row pass
+    over the faces between levels."""
+    telemetry.registry().reset()
+    s = PoissonSolver((6, 6, 6), mesh=mesh1(n_dev), dtype=jnp.float64,
+                      max_refinement_level=1)
+    g = s.grid
+    for c in (1, 44, 130, 216):
+        g.refine_completely(c)
+    g.stop_refining()
+    s.prepare()
+    assert g.plan.hoods[POISSON_NEIGHBORHOOD_ID].hard_nbr_rows is not None
+    cells = g.get_cells()
+    assert len(cells) > 216
+    p, q = np.random.default_rng(20261018).standard_normal((2, len(cells)))
+    g.set("p0", cells, p)
+    g.set("p1", cells, q)
+    s._exchange_p(["p0", "p1"])
+    s._apply(transpose=False)
+    s._apply(transpose=True)
+    q_ap = float(q @ g.get("Ap0", cells))
+    atq_p = float(g.get("r1", cells) @ p)
+    assert abs(q_ap - atq_p) <= 1e-12 * abs(q_ap), (q_ap, atq_p)
+    counts = _gather_programs()
+    assert counts.pop("table") == 2 and not any(counts.values()), counts
+
+
+@pytest.mark.parametrize("n_dev, shape, kind", [
+    (1, (8, 6, 4), "roll3d"),
+    (4, (8, 6, 4), "table"),
+    (4, (4, 4, 8), "roll_fixup"),
+])
+def test_uniform_matvec_matches_seven_point_stencil(n_dev, shape, kind):
+    """On a uniform periodic grid of unit cells A p is numpy's 7-point
+    stencil of p, through the slot gather the plan picks."""
+    telemetry.registry().reset()
+    s = PoissonSolver(shape, mesh=mesh1(n_dev), dtype=jnp.float64)
+    g = s.grid
+    s.prepare()
+    cells = g.get_cells()
+    p = np.random.default_rng(7).standard_normal(len(cells))
+    g.set("p0", cells, p)
+    s._exchange_p(["p0"])
+    s._apply(transpose=False)
+    counts = _gather_programs()
+    assert counts.pop(kind) == 1 and not any(counts.values()), counts
+    p3 = p.reshape(shape[::-1])  # cells in id order: x fastest
+    want = sum(np.roll(p3, 1, a) + np.roll(p3, -1, a) for a in range(3)) - 6 * p3
+    np.testing.assert_allclose(g.get("Ap0", cells), want.ravel(),
+                               rtol=0, atol=1e-12)

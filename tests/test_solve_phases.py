@@ -37,14 +37,19 @@ def _solver(n_dev=1):
     return s
 
 
-def _compiled_text(s):
-    """The compiled solve module's text, as the solve compiles it."""
+def _lowered(s):
+    """The solve program lowered for the arguments the solve passes."""
     s.prepare()
     prog, bindings = s._fused_solve_fn()
     g = s.grid
     args = (g.data["solution"], g.data["rhs"], g.data["Ap0"],
             jnp.asarray(1e-5, dtype=s.dtype), jnp.int32(1000), *bindings)
-    return prog.lower(*args).compile().as_text()
+    return prog.lower(*args)
+
+
+def _compiled_text(s):
+    """The compiled solve module's text, as the solve compiles it."""
+    return _lowered(s).compile().as_text()
 
 
 def _stripped(text):
@@ -103,6 +108,17 @@ def test_solve_counters_move_by_iterations_and_solves():
         done["iterations"] + capped["iterations"]
     assert reg.counter_value("dccrg_poisson_solves_total", converged="true") == 1
     assert reg.counter_value("dccrg_poisson_solves_total", converged="false") == 1
+
+
+def test_solve_program_reads_neighbors_through_roll3d():
+    """On one device both matvecs of the solve program are slot-wise on
+    the 3-D roll gather: two ``roll3d`` programs, no gather op and no
+    scatter but the write-backs of the three matvec calls."""
+    text = _lowered(_solver()).as_text()
+    assert telemetry.registry().counter_value(
+        "dccrg_slot_gather_programs_total", gather="roll3d") == 2
+    assert "stablehlo.gather" not in text
+    assert text.count("stablehlo.scatter") <= 6
 
 
 def test_prepare_sets_its_plan_phase_gauge():
