@@ -997,7 +997,10 @@ class Grid:
     # capacities whose arrays are small but whose need varies a lot
     # epoch-to-epoch (hard-shell sizes, pair lists, fixup widths):
     # give them a 2x band so shapes virtually never change
-    _WIDE_CAPS = ("G", "M", "S", "S_hard", "Hmax", "T_hard", "rollW", "removed")
+    _WIDE_CAPS = ("G", "M", "S", "S_hard", "Hmax", "T_hard", "rollW", "removed",
+                  "scatter")
+    # index buffers of per-epoch data movement: never shrink
+    _GROW_ONLY_CAPS = ("removed", "scatter")
 
     def _sticky_cap(self, name, needed: int) -> int:
         """Capacity with hysteresis: grow in buckets with headroom,
@@ -1010,8 +1013,8 @@ class Grid:
         base = name[0] if isinstance(name, tuple) else name
         wide = base in self._WIDE_CAPS
         prev = self._cap_memo.get(name)
-        if prev is not None and needed <= prev and base == "removed":
-            return prev  # tiny index buffer: never shrink
+        if prev is not None and needed <= prev and base in self._GROW_ONLY_CAPS:
+            return prev
         if prev is not None and prev // (4 if wide else 2) <= needed <= prev:
             return prev
         if prev is None:
@@ -1737,12 +1740,14 @@ class Grid:
             values = np.broadcast_to(
                 values, (len(flat),) + self.fields[name][0])[keep]
         n = len(rows)
-        # fixed small tier, then buckets: adapt-epoch projection writes
-        # (new children / unrefined parents, surface-sized) all land in
-        # ONE program per field regardless of their per-epoch drift
-        # (the zero-new-programs invariant, test_advection_amr); only
-        # rare large landings (balance restructure) take bucketed caps
-        cap = _GATHER_TIER if n <= _GATHER_TIER else bucket_capacity(n)
+        # fixed small tier, then a grow-only sticky cap per (shape,
+        # dtype): adapt-epoch projection writes (new children /
+        # unrefined parents, surface-sized) land in one program per
+        # field whatever their per-epoch drift (the zero-new-programs
+        # invariant, test_advection_amr), at small bases in the tier
+        # and at large ones in the sticky cap
+        cap = (_GATHER_TIER if n <= _GATHER_TIER
+               else self._sticky_cap(("scatter", shape, str(dtype)), n))
         R = self.plan.R
         dev_p = np.zeros(cap, dtype=np.int32)
         row_p = np.full(cap, R - 1, dtype=np.int32)
@@ -2789,8 +2794,11 @@ class Grid:
         gathers neighbors and runs ``kernel`` (same signature as
         apply_stencil's) over every local row, then writes the result
         into ``fields_out``: scopes ``dccrg.exchange``, ``dccrg.bulk``
-        and ``dccrg.apply``. The whole time loop is a single XLA
-        program (lax.fori_loop) with no host round-trips. The
+        and ``dccrg.apply``; a hybrid plan's hard rows (cells near a
+        change of refinement level, whose neighbours sit in their own
+        wider tables) are recomputed in ``dccrg.hard``. The whole time
+        loop is a single XLA program (lax.fori_loop) with no host
+        round-trips. The
         reference's start/solve-inner/wait/solve-outer overlap
         (dccrg.hpp:5046-5413, tests/advection/2d.cpp:327-343) is not
         restated inside it: the bulk pass reads the landed ghosts.
@@ -2989,7 +2997,7 @@ class Grid:
                         result = kernel(cell_fields, nbr_fields, noffs,
                                         nmask, *extra)
                 if split:
-                    with jax.named_scope("dccrg.bulk"):
+                    with jax.named_scope("dccrg.hard"):
                         h_cell = {n: cell_fields[n][hrc] for n in fields_in}
                         h_nbr = {n: full[n][hnr] for n in fields_in}
                         h_result = kernel(h_cell, h_nbr, hof, hm, *extra)

@@ -29,7 +29,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..grid import Grid
+from .. import telemetry
+from ..grid import DEFAULT_NEIGHBORHOOD_ID, Grid
 from ..neighbors import face_masks
 
 STATIC_FIELDS = ("vx", "vy", "vz", "lx", "ly", "lz", "ilen")
@@ -118,11 +119,14 @@ def make_diff_kernel(diff_threshold: float):
 
 class AmrAdvection:
     """The reference test's main program (tests/advection/2d.cpp):
-    solve / adapt every ``adapt_n`` / balance every ``balance_n``."""
+    solve / adapt every ``adapt_n`` / balance every ``balance_n``.
+    ``dtype`` is the storage dtype of the density; the static fields
+    stay float32."""
 
     def __init__(self, length=(32, 32, 1), max_refinement_level=1, mesh=None,
                  cfl=0.5, diff_increase=0.02, diff_threshold=0.025,
-                 unrefine_sensitivity=0.5, partition=None):
+                 unrefine_sensitivity=0.5, partition=None,
+                 dtype=jnp.float32):
         self.cfl = cfl
         self.diff_increase = diff_increase
         self.diff_threshold = diff_threshold
@@ -130,7 +134,7 @@ class AmrAdvection:
         cell_len = tuple(1.0 / n for n in length)
         self.grid = (
             Grid(cell_data={
-                "density": jnp.float32, "flux": jnp.float32,
+                "density": dtype, "flux": jnp.float32,
                 "max_diff": jnp.float32,
                 "vx": jnp.float32, "vy": jnp.float32, "vz": jnp.float32,
                 "lx": jnp.float32, "ly": jnp.float32, "lz": jnp.float32,
@@ -306,14 +310,42 @@ class AmrAdvection:
         return ids, codes
 
     def adapt(self) -> tuple:
-        """check_for_adaptation + adapt_grid: returns (created, removed)."""
+        """check_for_adaptation + adapt_grid: returns (created, removed).
+        Host span ``amr.adapt`` (the plan rebuild inside it is
+        ``grid.recommit``); counters ``dccrg_amr_epochs_total``,
+        ``dccrg_amr_cells_created_total``, ``dccrg_amr_cells_removed_total``
+        and the gauge ``dccrg_amr_hard_rows``, the hard rows of the new
+        plan."""
+        with telemetry.span("amr.adapt"):
+            if self.grid.mapping.max_refinement_level == 0:
+                return (np.empty(0, np.uint64), np.empty(0, np.uint64))
+            created, removed = self._adapt()
+            telemetry.inc("dccrg_amr_epochs_total")
+            telemetry.inc("dccrg_amr_cells_created_total", len(created))
+            telemetry.inc("dccrg_amr_cells_removed_total", len(removed))
+            telemetry.set_gauge("dccrg_amr_hard_rows", self.hard_rows())
+        return created, removed
+
+    def hard_rows(self) -> int:
+        """Rows of the plan's hard-row pass (cells near a change of
+        refinement level; 0 on a plan without one)."""
+        plan = self.grid.plan
+        rows = plan.hoods[DEFAULT_NEIGHBORHOOD_ID].hard_rows
+        return 0 if rows is None else int(np.sum(np.asarray(rows) < plan.L))
+
+    def compute_max_diff(self) -> None:
+        """The criterion stencil (adapter.hpp:47-178): every local
+        cell's ``max_diff`` over its face neighbours, on the current
+        plan. Writes nothing but ``max_diff``."""
         g = self.grid
-        if g.mapping.max_refinement_level == 0:
-            return (np.empty(0, np.uint64), np.empty(0, np.uint64))
         g.update_copies_of_remote_neighbors(fields=["density"])
         g.apply_stencil(
             self._diff_kernel, ["density", "ilen"], ["max_diff"]
         )
+
+    def _adapt(self) -> tuple:
+        g = self.grid
+        self.compute_max_diff()
         ids, codes = self._flagged_cells()
         to_refine = ids[codes == 1]
         keep = ids[codes == 2]

@@ -1,10 +1,15 @@
 """AMR advection tests — the reference advection test's full loop
 (solve + adapt + balance) on the general grid path."""
 
+import importlib.util
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from dccrg_tpu.models.advection import AdvectionSolver
@@ -178,3 +183,121 @@ def test_adapt_epochs_reuse_compiled_programs():
     assert dict(g._cap_memo) == caps_before, "capacities flapped"
     new = set(g._program_cache) - keys_before
     assert not new, f"programs recompiled: {[k[0] for k in new]}"
+
+
+def test_drifting_partial_writes_reuse_one_scatter_program():
+    """Partial writes above the small tier (an adapt epoch's projection
+    at a large base) land in a grow-only sticky capacity: once it has
+    grown, writes that drift in size add no scatter program."""
+    amr = AmrAdvection((128, 128, 1), max_refinement_level=0,
+                       mesh=mesh_of(1))
+    g = amr.grid
+    cells = g.get_cells()
+
+    def scatters():
+        return {k for k in g._program_cache if k[0] == "devscatter"}
+
+    for n in (5000, 7000):  # the first bucket, then one growth
+        g.set("density", cells[:n], np.ones(n, np.float32))
+    before = scatters()
+    for n in (4500, 9000, 6000, 13000):
+        g.set("density", cells[:n], np.full(n, 2.0, np.float32))
+    assert scatters() == before
+    np.testing.assert_array_equal(g.get("density", cells[:13000]), 2.0)
+    np.testing.assert_array_equal(g.get("density", cells[13000:]), 0.0)
+
+
+# -- against the plain reference ---------------------------------------
+# The AMR cell's driver holds the plain reference (numpy, importing
+# nothing of dccrg_tpu) and the moves between the grid and its fine
+# lattice; the tests load that one copy by path.
+
+BENCH = Path(__file__).resolve().parents[1] / "benchmark"
+_spec = importlib.util.spec_from_file_location(
+    "advection_amr_driver", BENCH / "drivers" / "advection_amr.py")
+ref = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(ref)
+CONFIG = json.loads(
+    (BENCH / "configs" / "advection3d-amr1-64.json").read_text())
+CRITERION = ref.criterion(CONFIG)
+BASE = 8
+CYCLES = 3
+STEPS = 4
+
+
+def _amr_cycles(g, dtype=jnp.float32, n=BASE):
+    """Image ``g`` refined as the reference refines it, then CYCLES of
+    ``run_fused(STEPS)`` and ``adapt()`` on one device. Returns per
+    epoch (cells, hard rows, created, removed, level mask) and the
+    final fine-lattice density."""
+    field = ref.image(ref.initial_density(n), g)
+    amr = AmrAdvection((n, n, n), max_refinement_level=1, mesh=mesh_of(1),
+                       dtype=dtype, **CRITERION)
+    ref.put_density(amr.grid, ref.coarsen(field, np.zeros((n, n, n), np.int8)))
+    amr.adapt()
+    ref.put_density(amr.grid, field)
+    dt = ref.time_step(n, CONFIG["cfl"])
+    epochs = []
+    for _ in range(CYCLES):
+        amr.run_fused(STEPS, dt)
+        created, removed = amr.adapt()
+        rho, level = ref.take_density(amr.grid, n)
+        epochs.append((len(amr.grid.get_cells()), amr.hard_rows(),
+                       len(created), len(removed), level))
+    return epochs, rho
+
+
+def _reference_cycles(g, n=BASE):
+    rho, level = ref.initial_state(ref.image(ref.initial_density(n), g),
+                                   **CRITERION)
+    dt = ref.time_step(n, CONFIG["cfl"])
+    epochs = []
+    for _ in range(CYCLES):
+        for _ in range(STEPS):
+            rho = ref.step(rho, level, dt)
+        rho, level, created, removed = ref.adapt(rho, level, **CRITERION)
+        epochs.append((ref.cell_count(level), created, removed, level))
+    return epochs, rho
+
+
+@pytest.fixture(scope="module")
+def identity_run():
+    return _amr_cycles(0)
+
+
+def test_cycles_match_plain_reference(identity_run):
+    """3 cycles of run_fused(4) and adapt() against the plain reference:
+    the same roots refined at every epoch, the same counts, and the
+    density within the configuration's limit."""
+    epochs, rho = identity_run
+    want, want_rho = _reference_cycles(0)
+    for (cells, _, created, removed, level), (w_cells, w_cr, w_rm, w_level) \
+            in zip(epochs, want):
+        assert (cells, created, removed) == (w_cells, w_cr, w_rm)
+        np.testing.assert_array_equal(level, w_level)
+    assert any(e[1] for e in epochs), "no hard rows: nothing refined"
+    assert np.max(np.abs(rho - want_rho)) <= CONFIG["limit"]["max_abs_err"]
+
+
+def test_bf16_control_fails_the_comparison():
+    """The configuration's control (bfloat16 density) must fail the
+    comparison the float32 run passes."""
+    assert CONFIG["control"] == {"dtype": "bfloat16"}
+    epochs, rho = _amr_cycles(0, dtype=jnp.bfloat16)
+    want, want_rho = _reference_cycles(0)
+    mismatch = int(np.sum(epochs[-1][-1] != want[-1][-1]))
+    err = float(np.max(np.abs(rho - want_rho)))
+    assert mismatch > CONFIG["limit"]["level_mismatch"] \
+        or err > CONFIG["limit"]["max_abs_err"]
+
+
+@pytest.mark.parametrize("g", range(1, 8))
+def test_seed_images_do_the_same_work(identity_run, g):
+    """Every seed image refines, coarsens and steps the same number of
+    cells, hard rows included, at every epoch, and its refined set is
+    the image of the identity's."""
+    base, _ = identity_run
+    epochs, _ = _amr_cycles(g)
+    assert [e[:4] for e in epochs] == [e[:4] for e in base]
+    for e, b in zip(epochs, base):
+        np.testing.assert_array_equal(e[4], ref.image(b[4], g))

@@ -11,6 +11,7 @@ import pytest
 from dccrg_tpu import Grid, telemetry
 from dccrg_tpu.grid import default_mesh
 from dccrg_tpu.models.advection import GridAdvection
+from dccrg_tpu.models.advection_amr import AmrAdvection
 
 pytestmark = pytest.mark.telemetry
 
@@ -66,6 +67,63 @@ def test_published_table_maps_every_op(tmp_path, n_dev, want):
     assert set(table.values()) - {"unscoped"} == want
     assert telemetry.registry().gauge_value(
         "dccrg_scope_table_seconds", module="jit_dccrg_step_loop") > 0
+
+
+AMR_FIELDS = ["density", "vx", "vy", "vz", "lx", "ly", "lz", "ilen"]
+
+
+def _scopes_of(grid, kernel, fields_in, dt):
+    """The op -> scope table of the compiled step program."""
+    fn, tables, static_in = grid.compile_step_loop(
+        kernel, fields_in, ["density"], n_extra=1)
+    args = (jnp.int32(1), *tables, *(grid.data[n] for n in static_in),
+            grid.data["density"], jnp.float32(dt))
+    return telemetry.scope_table(fn.lower(*args).compile().as_text())
+
+
+def test_hybrid_plan_scopes_its_hard_rows():
+    """A refined grid's hybrid plan recomputes its hard rows in their
+    own scope, beside the bulk pass."""
+    amr = AmrAdvection((8, 8, 1), max_refinement_level=1,
+                       mesh=default_mesh(jax.devices()[:1]))
+    amr.adapt()
+    assert amr.hard_rows() > 0
+    scopes = set(_scopes_of(amr.grid, amr._fused_kernel, AMR_FIELDS,
+                            1e-3).values())
+    assert {"dccrg.bulk", "dccrg.hard", "dccrg.apply"} <= scopes
+
+
+def test_uniform_plan_has_no_hard_scope():
+    solver = GridAdvection(n=16, nz=16, mesh=default_mesh(jax.devices()[:1]))
+    scopes = set(_scopes_of(solver.grid, solver._kernel,
+                            ["density", "vx", "vy"], 1e-3).values())
+    assert "dccrg.bulk" in scopes and "dccrg.hard" not in scopes
+
+
+def test_adapt_counts_one_epoch_and_nests_the_recommit():
+    """One adapt(): one epoch on the counters, the cells it made and
+    removed, the new plan's hard rows on the gauge, and an ``amr.adapt``
+    span that holds the plan rebuild's ``grid.recommit``."""
+    amr = AmrAdvection((8, 8, 1), max_refinement_level=1,
+                       mesh=default_mesh(jax.devices()[:1]))
+    reg = telemetry.registry()
+    telemetry.configure(trace=True)
+    telemetry.clear_trace()
+    try:
+        created, removed = amr.adapt()
+        evs = telemetry.events()
+    finally:
+        telemetry.configure(trace=None)
+        telemetry.clear_trace()
+    assert reg.counter_value("dccrg_amr_epochs_total") == 1
+    assert reg.counter_value("dccrg_amr_cells_created_total") == len(created) > 0
+    assert reg.counter_value("dccrg_amr_cells_removed_total") == len(removed)
+    assert reg.gauge_value("dccrg_amr_hard_rows") == amr.hard_rows() > 0
+    (outer,) = [e for e in evs if e["name"] == "amr.adapt"]
+    (inner,) = [e for e in evs if e["name"] == "grid.recommit"]
+    assert inner["depth"] > outer["depth"] == 0
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
 
 
 def test_no_table_without_a_session():
